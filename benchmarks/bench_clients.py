@@ -41,6 +41,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import clients as vclients
 from repro.core import hier, votes
 from repro.core.topology import single_device_topology
+from repro.launch import compile_cache
 
 # the cost-model EMNIST MLP (benchmarks/cost_model.D_PARAMS)
 DIN, HID, DOUT = 784, 64, 10
@@ -160,6 +161,7 @@ def main() -> None:
         pathlib.Path(__file__).resolve().parents[1]
         / "BENCH_clients.json"))
     args = ap.parse_args()
+    compile_cache.enable()
 
     topo = single_device_topology()
     sweep = K_SWEEP_FAST if args.fast else K_SWEEP

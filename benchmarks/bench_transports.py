@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from benchmarks import hlo_analysis
 from repro.core import flatbuf, signs, votes
 from repro.core.topology import single_device_topology
+from repro.launch import compile_cache
 
 MU, RHO = 1e-3, 0.2
 
@@ -149,6 +150,7 @@ def main() -> None:
         pathlib.Path(__file__).resolve().parents[1]
         / "BENCH_transports.json"))
     args = ap.parse_args()
+    compile_cache.enable()
 
     topo = single_device_topology()
     sizes = [int(float(s)) for s in args.sizes.split(",")]

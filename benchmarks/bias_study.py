@@ -55,6 +55,7 @@ import numpy as np
 from repro.core import clients as vclients
 from repro.core import ref_fed
 from repro.data import emnist_like
+from repro.launch import compile_cache
 from repro.models import mlp
 
 METHODS = ("hier_sgd", "hier_signsgd", "dc_hier_signsgd",
@@ -174,6 +175,7 @@ def main() -> None:
     ap.add_argument("--out", default=str(
         pathlib.Path(__file__).resolve().parents[1] / "BENCH_bias.json"))
     args = ap.parse_args()
+    compile_cache.enable()
 
     prof = _profile(args.fast)
     cells = []

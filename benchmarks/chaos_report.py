@@ -37,6 +37,7 @@ import numpy as np
 
 import parity_harness as H
 from repro.core.topology import single_device_topology
+from repro.launch import compile_cache
 
 REPORT = (pathlib.Path(__file__).resolve().parents[1] / "reports"
           / "chaos_cells.json")
@@ -55,6 +56,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=str(REPORT))
     args = ap.parse_args()
+    compile_cache.enable()
 
     topo = single_device_topology()
     problem = H.make_problem(1, 1)

@@ -48,6 +48,8 @@ def main() -> None:
     sys.path.insert(0, str(root))
     sys.path.insert(0, str(root / "src"))
     from benchmarks import cost_model, paper_figs, roofline
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     rows = []
     want = lambda k: args.only in ("all", k)

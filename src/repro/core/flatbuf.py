@@ -7,9 +7,11 @@ a **static leaf layout** for any float pytree so the hot path can operate on
 ONE contiguous ``[..., n_pad]`` buffer (or its 1-bit packed twin) instead:
 
   * every leaf is assigned a coordinate range ``[offset, offset + size)``
-    with ``offset % 32 == 0`` (leaf tails padded to the 32-bit pack word),
-    so the float and packed-word domains share the same layout:
-    leaf i's words are exactly ``[offset/32, (offset + padded)/32)``;
+    with ``offset % 128 == 0`` (leaf tails padded to a whole 128-lane
+    row, so buffers assemble as (8, 128)-tiled lane rows and every slot
+    is also whole 32-bit pack words): the float and packed-word domains
+    share the same layout -- leaf i's words are exactly
+    ``[offset/32, (offset + padded)/32)``;
   * the total is padded to the 32*128 TPU tile (one packed word per lane),
     so 2D views handed to the Pallas kernels need no further padding;
   * dtype promotion rule: the buffer dtype is ``jnp.promote_types`` over
@@ -134,8 +136,8 @@ class LeafSlot:
     shape: tuple[int, ...]       # leaf dims (batch dims excluded)
     dtype: Any                   # original leaf dtype (restored on unflatten)
     size: int                    # prod(shape)
-    padded: int                  # size padded to a PACK multiple
-    offset: int                  # coordinate offset; offset % PACK == 0
+    padded: int                  # size padded to a LANES multiple
+    offset: int                  # coordinate offset; offset % LANES == 0
     shard_dim: int | None = None  # model-sharded leaf dim (sharded layouts)
     shard_pad: int = 0           # zero tail padding the global shard_dim
                                  # extent up to a multiple of shards
@@ -368,7 +370,7 @@ def make_layout(tree: PyTree, batch_dims: int = 0, tile: int = TILE,
             sp = blk * shards - shape[sd]
             shape = shape[:sd] + (blk,) + shape[sd + 1:]
         size = int(functools.reduce(lambda a, b: a * b, shape, 1))
-        padded = _ceil_to(max(size, 1), PACK)
+        padded = _ceil_to(max(size, 1), LANES)
         slots.append(LeafSlot(shape=shape, dtype=leaf.dtype, size=size,
                               padded=padded, offset=offset, shard_dim=sd,
                               shard_pad=sp))
@@ -478,13 +480,23 @@ def flatten_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
              for t in bucket_trees(layout, tree, batch_dims)], axis=-1)
     dtype = layout.dtype if dtype is None else dtype
     leaves = layout.treedef.flatten_up_to(tree)
-    parts = [_flat_leaf(s, leaf.astype(dtype), batch_dims)
+    batch = leaves[0].shape[:batch_dims]
+    nb = int(functools.reduce(lambda a, b: a * b, batch, 1))
+    # assemble as [nb, n_pad/128, 128] lane rows (slots are whole rows)
+    # over ONE merged batch dim, the tail padding a piece of the same
+    # concatenate: the TPU compiler turns a rank-3 [P, D, n] concat of
+    # size-1 batch dims into code that grows with n (minutes and GiBs
+    # of host memory at 1B parameters), and a [1, n] array of a 16- or
+    # 8-bit dtype occupies 2x / 4x its bytes in HBM, while the lane-row
+    # form is (8, 128)-tiled -- for f32 byte-for-byte the flat buffer
+    parts = [_flat_leaf(s, leaf.astype(dtype).reshape(
+                 (nb,) + leaf.shape[batch_dims:]), 1).reshape(nb, -1, LANES)
              for s, leaf in zip(layout.slots, leaves)]
-    buf = jnp.concatenate(parts, axis=-1)
-    tail = layout.n_pad - buf.shape[-1]
+    tail = layout.n_pad // LANES - sum(p.shape[1] for p in parts)
     if tail:
-        buf = jnp.pad(buf, [(0, 0)] * batch_dims + [(0, tail)])
-    return buf
+        parts.append(jnp.zeros((nb, tail, LANES), dtype))
+    buf = jnp.concatenate(parts, axis=1)
+    return buf.reshape(batch + (layout.n_pad,))
 
 
 def unflatten_tree(layout: FlatLayout, buf: jax.Array, batch_dims: int = 0,
@@ -520,6 +532,9 @@ def unflatten_tree(layout: FlatLayout, buf: jax.Array, batch_dims: int = 0,
                 leaves.append(full)
         return layout.treedef.unflatten(leaves)
     batch = buf.shape[:batch_dims]
+    if batch_dims > 1:      # slice ONE merged batch dim (see flatten_tree)
+        buf = buf.reshape(int(functools.reduce(lambda a, b: a * b, batch, 1)),
+                          buf.shape[-1])
     leaves = []
     for s in layout.slots:
         leaf = buf[..., s.offset:s.offset + s.size].reshape(batch + s.shape)
@@ -573,7 +588,12 @@ def pack_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
             dlf = dl.reshape(dl.shape[:delta_batch_dims] + (slot.size,))
             dlf = _with_mid_axes(dlf, delta_batch_dims, batch_dims)
             u = u + rho * dlf.astype(u.dtype)
-        parts.append(signs.pack_signs(signs.sgn(u)))      # pads to +1 bits
+        w = signs.pack_signs(signs.sgn(u))                # pads to +1 bits
+        if w.shape[-1] != slot.words:
+            w = jnp.pad(w, [(0, 0)] * batch_dims
+                        + [(0, slot.words - w.shape[-1])],
+                        constant_values=jnp.uint32(0xFFFFFFFF))
+        parts.append(w)
     words = jnp.concatenate(parts, axis=-1)
     tail = layout.n_words - words.shape[-1]
     if tail:

@@ -443,16 +443,11 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             pt = master_views(params) if flat else params
             p, d = topo.pods, topo.devices_per_pod
             rngs3 = rngs.reshape((p, d, cc.count) + rngs.shape[2:])
-            if flat:
-                acc0 = topo.constrain(
-                    jnp.zeros((p, d, params.layout.n_pad), jnp.float32),
-                    flat_spec(params.layout, 2))
-            else:
-                acc0 = jax.tree.map(
-                    lambda v, cs: topo.constrain(
-                        jnp.zeros((p, d) + v.shape[1:], jnp.float32),
-                        topo.dev_spec(*cs)),
-                    pt, bundle.compute_specs)
+            acc0 = jax.tree.map(
+                lambda v, cs: topo.constrain(
+                    jnp.zeros((p, d) + v.shape[1:], jnp.float32),
+                    topo.dev_spec(*cs)),
+                pt, bundle.compute_specs)
 
             def abody(c_idx, acc):
                 b_c = vclients.client_slice(batch, cc.count, c_idx)
@@ -461,37 +456,15 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                 g_c, _ = per_device_grads(pt, b_c, r_c, devices=d)
                 sh_c = jax.lax.dynamic_index_in_dim(dev_w, c_idx, axis=2,
                                                     keepdims=False)
-                if flat:
-                    g_buf = flatten_buf(params.layout, g_c, 2, jnp.float32)
-                    return acc + g_buf * sh_c[:, :, None]
                 return jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32) * sh_c.reshape(
                         sh_c.shape + (1,) * (g.ndim - 2)), acc, g_c)
 
             acc = jax.lax.fori_loop(0, cc.count, abody, acc0)
-            if flat:
-                c_q = jnp.sum(acc, axis=1)
-                c = votes.pod_weighted_average(topo, c_q, edge_w)
-                delta = (c - c_q).astype(algo.delta_dtype)
-                return constrain_master(flatbuf.FlatState(
-                    delta,
-                    flatbuf.with_dtype(params.layout, algo.delta_dtype)))
             c_q = jax.tree.map(lambda a: jnp.sum(a, axis=1), acc)
-        elif flat:
-            # the anchor stays flat: one weighted-mean + one pod
-            # all-reduce over the whole-model buffer, and the delta the
-            # local steps consume is the buffer itself (the pre-sign
-            # correction u + rho*delta is one fused elementwise op).
-            g_dev, _ = per_device_grads(master_views(params), batch, rngs)
-            g_buf = flatten_buf(params.layout, g_dev, 2, jnp.float32)
-            c_q = votes.weighted_mean_dev(topo, g_buf, dev_w,
-                                          clients=k_merge)
-            c = votes.pod_weighted_average(topo, c_q, edge_w)
-            delta = (c - c_q).astype(algo.delta_dtype)
-            return constrain_master(flatbuf.FlatState(
-                delta, flatbuf.with_dtype(params.layout, algo.delta_dtype)))
         else:
-            g_dev, _ = per_device_grads(params, batch, rngs)
+            g_dev, _ = per_device_grads(
+                master_views(params) if flat else params, batch, rngs)
             c_q = jax.tree.map(
                 lambda g: votes.weighted_mean_dev(
                     topo, g.astype(jnp.float32), dev_w, clients=k_merge),
@@ -499,6 +472,13 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
         c = pod_avg(c_q, edge_w)
         delta = jax.tree.map(lambda a, b: (a - b).astype(algo.delta_dtype),
                              c, c_q)
+        if flat:
+            # the anchor's f32 statistics stay per leaf in both layouts
+            # (no f32 whole-model buffer beside the gradients); only the
+            # delta the local steps fold pre-sign lands in a flat buffer
+            return constrain_master(flatbuf.FlatState(
+                flatten_buf(params.layout, delta, 1, algo.delta_dtype),
+                flatbuf.with_dtype(params.layout, algo.delta_dtype)))
         return constrain_master(delta)
 
     # ---------------- scaffold / mtgc correction refresh -----------------
@@ -872,10 +852,12 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
         g_dev, losses = per_device_grads(master_views(params), batch, rngs)
         new_ef, new_mom = state.ef, state.mom
 
-        def descend(direction_tree):
-            dir_buf = flatten_buf(layout, direction_tree, 1,
-                                  params.buf.dtype)
-            return params.replace(params.buf - mu * dir_buf)
+        def descend(vote_tree):
+            # flatten the int8 vote (exact in any float dtype) and cast
+            # inside the update: no f32 copy of the direction
+            dir_buf = flatten_buf(layout, vote_tree, 1, jnp.int8)
+            return params.replace(
+                params.buf - mu * dir_buf.astype(params.buf.dtype))
 
         if algo.method == "hier_sgd":
             g_buf = flatten_buf(layout, g_dev, 2, jnp.float32)
@@ -1045,8 +1027,11 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                     vlayout = lay if lay.shards > 1 else None
                 if vlayout is None:
                     vlayout = flatbuf.make_layout(template, batch_dims=2)
+            # lane rows [P, D, n_pad/128, 128]: the kernels' own view,
+            # and an 8-bit [P, D, n_pad] array would take 4x its bytes
             tally_flat = topo.constrain(
-                jnp.zeros((p, d, vlayout.n_pad), acc_dt),
+                jnp.zeros((p, d, vlayout.n_pad // flatbuf.LANES,
+                           flatbuf.LANES), acc_dt),
                 shardflat.buf_spec(topo, vlayout, 2))
         else:
             tally_tree = jax.tree.map(

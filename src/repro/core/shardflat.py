@@ -4,7 +4,7 @@ A sharded :class:`~repro.core.flatbuf.FlatLayout` (``layout.shards >
 1``) assigns each model (TP) shard one contiguous, tile-aligned bucket
 of the flat coordinate space.  This module moves trees in and out of
 that buffer **without any model-axis communication**: every operation
-is a ``jax.experimental.shard_map`` program in which rank m runs the
+is a ``jax.shard_map`` program in which rank m runs the
 ordinary ``flatbuf`` flatten/unflatten on its *local* leaf blocks with
 ``layout.bucket()`` -- no concatenate ever crosses a shard boundary, so
 neither XLA's concat partitioner (which PR 2 had to dodge with
@@ -17,7 +17,7 @@ with the bucket geometry):
   * buffer  ``[P(, D), n_pad]``      -> ``P(pod(, data), model)``
   * sharded leaf                     -> model axis on ``slot.shard_dim``
   * per-bucket-copy leaf             -> replicated over model (each rank
-    holds the identical copy; ``check_rep=False`` because shard_map
+    holds the identical copy; ``check_vma=False`` because shard_map
     cannot prove the replication invariant the layout guarantees)
 
 Uneven sharded leaves (``slot.shard_pad > 0``) cross the shard_map
@@ -30,7 +30,7 @@ unevenly sharded dim IS the ceil-padded form), so they lower without
 model-axis communication; the zero tail is don't-care exactly like
 tile padding.
 
-``check_rep=False`` is safe here by construction: copies are only ever
+``check_vma=False`` is safe here by construction: copies are only ever
 written from model-replicated inputs through deterministic elementwise
 programs, so they remain bit-identical on every rank.
 """
@@ -39,7 +39,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import flatbuf
@@ -81,8 +80,8 @@ def leaf_specs(topo: Topology, layout: flatbuf.FlatLayout,
 
 
 def _smap(topo: Topology, fn, in_specs, out_specs):
-    return shard_map(fn, mesh=topo.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=topo.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def flatten(topo: Topology, layout: flatbuf.FlatLayout, tree: PyTree,

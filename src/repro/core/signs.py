@@ -61,10 +61,14 @@ def pack_signs(signs: jax.Array) -> jax.Array:
     Positive sign -> bit 1.  Padding bits are 1 (+1 sign).
     """
     flat = _pad_to_multiple(signs, PACK_WIDTH)
-    bits = (flat > 0).astype(jnp.uint32)
-    bits = bits.reshape(bits.shape[:-1] + (-1, PACK_WIDTH))
-    shifts = jnp.arange(PACK_WIDTH, dtype=jnp.uint32)
-    return jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
+    bits = (flat > 0).reshape(flat.shape[:-1] + (-1, PACK_WIDTH))
+    # an OR of 32 shifted bit slices (disjoint bits: equal to their
+    # sum) fuses into one elementwise pass; a sum over a shifted
+    # [..., 32] uint32 tensor makes XLA materialize 4 bytes per sign
+    word = bits[..., 0].astype(jnp.uint32)
+    for j in range(1, PACK_WIDTH):
+        word = word | (bits[..., j].astype(jnp.uint32) << jnp.uint32(j))
+    return word
 
 
 def unpack_signs(words: jax.Array, n: int) -> jax.Array:

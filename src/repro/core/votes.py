@@ -17,13 +17,14 @@ transport     wire format     HBM passes per local step    fallback rules
 ``fused``     1 bit/coord,    ONE flat word buffer for     FSDP regime and
               one contiguous  the whole model: per-leaf    per-leaf callers
               word buffer     fused (g + rho*delta) ->     -> ``ag_packed``;
-              (flatbuf        sign -> pack, word-level     model axis > 1 ->
-              layout)         concat (1/32 of the tally),  shard_map program
-                              ONE data-axis gather, ONE    on per-shard
-                              popcount vote + update       buckets (kernels
-                                                           per rank on TPU);
-                                                           off-TPU -> pure
-                                                           jnp (bit-ident.)
+              (flatbuf        sign -> pack, word-level     model axis > 1 or
+              layout)         concat (1/32 of the tally),  kernels on >1
+                              ONE data-axis gather, ONE    device ->
+                              popcount vote + update       shard_map program
+                                                           (kernels per rank
+                                                           on TPU); off-TPU
+                                                           -> pure jnp
+                                                           (bit-identical)
 ``mean`` /    32 bits/coord   full-precision weighted      --
 ``wmean``                     averaging (HierSGD)
 ============  ==============  ===========================  =================
@@ -44,17 +45,18 @@ transport     wire format     HBM passes per local step    fallback rules
     ``core.flatbuf`` into one 32*128-tile-aligned coordinate space; devices
     emit a single contiguous packed uplink row per step with the DC
     correction fused pre-sign (Alg. 2's device-side step), ONE gather moves
-    it, and ONE fused popcount-vote produces the per-pod direction.  On a
-    single-device TPU mesh the local compute runs the Pallas kernels
-    (``kernels.sign_pack`` / ``kernels.vote_update``); on a multi-chip
-    mesh with a >1 model axis the whole chain runs as a ``shard_map``
-    program over a *sharded* flatbuf layout (per-model-shard buckets):
-    each rank sign-packs its own bucket (Pallas on TPU), the packed
-    words are all-gathered over ``data`` INSIDE the program -- the only
-    collective -- and each rank votes/updates its local shard, so no
-    whole-leaf gather and no unsharded bit tensor ever exist.
-    Everywhere else a pure-jnp path with identical arithmetic runs
-    (GSPMD partitions it).  All three sign transports are bit-identical
+    it, and ONE fused popcount-vote produces the per-pod direction.  On
+    TPU the local compute runs the Pallas kernels (``kernels.sign_pack``
+    / ``kernels.vote_update``).  They are single-device programs, so on a
+    multi-device TPU mesh -- and on every mesh with a >1 model axis,
+    whose flatbuf layout is *sharded* (per-model-shard buckets) -- the
+    whole chain runs as a per-rank ``shard_map`` program: each rank
+    sign-packs its own bucket (Pallas on TPU), the packed words are
+    all-gathered over ``data`` INSIDE the program -- the only collective
+    -- and each rank votes/updates its local shard, so no whole-leaf
+    gather and no unsharded bit tensor ever exist.  Off-TPU without a
+    model axis a pure-jnp path with identical arithmetic runs (GSPMD
+    partitions it).  All three sign transports are bit-identical
     (ties -> +1) by construction.  Requires the replicated regime.
 
 State layouts (``AlgoConfig.state_layout``, see ``core.flatbuf``):
@@ -86,7 +88,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import flatbuf, shardflat, signs
@@ -359,6 +360,15 @@ def _fused_kernel_bufs(layout, u_dev, delta_tree, delta_buf, rho):
     return u_buf, d_buf
 
 
+def _per_rank(topo: Topology, layout: flatbuf.FlatLayout, mode: str) -> bool:
+    """Run the fused chain as the per-rank ``shard_map`` program?
+
+    Always for a model-sharded layout; and whenever the kernels run on
+    a multi-device mesh -- they are single-device programs, so every
+    rank must run its own (GSPMD cannot partition a Pallas call)."""
+    return layout.shards > 1 or (topo.mesh.size > 1 and mode != "jnp")
+
+
 def _packed_vote(topo, layout, u_dev, delta_tree, rho, mask):
     """jnp route: per-leaf fused pack (correction pre-sign), ONE
     data-axis gather of the 1-bit payload, one popcount -> [P, n_pad]."""
@@ -404,7 +414,7 @@ def _fused_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
     u_dev = flatbuf.pad_tree(layout, u_dev, 2)
     if delta_tree is not None:
         delta_tree = flatbuf.pad_tree(layout, delta_tree, 1)
-    mode = kops.fused_kernel_mode(topo.mesh.size, shard_mapped=True)
+    mode = kops.fused_kernel_mode()
     use_kernel = mode in ("pallas", "interpret")
     interpret = mode == "interpret"
     want_update = v_buf is not None
@@ -470,8 +480,8 @@ def _fused_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
 
     out_specs = (shardflat.buf_spec(topo, layout, 1) if want_update
                  else shardflat.leaf_specs(topo, layout, 1))
-    fn = shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     out = fn(*args)
     if want_update:
         return out
@@ -494,23 +504,26 @@ def fused_sign_vote(topo: Topology, u_dev, delta=None, rho: float = 0.0,
     Chain: per-leaf fused sign+pack into ONE contiguous word buffer
     (``flatbuf`` layout; the f32 flat buffer is never materialized on the
     jnp path), one data-axis gather of the packed words, one popcount
-    vote.  On a single-device TPU mesh the local sweeps instead run the
-    Pallas kernels over the flat f32 view (``kernels.ops``).
+    vote.  On TPU the local sweeps instead run the Pallas kernels over
+    the flat view (``kernels.ops``), per rank under ``shard_map`` when
+    the mesh has more than one device (:func:`_fused_shard_map`).
 
     specs: optional per-leaf PartitionSpec pytree (leaf dims).  On a
     mesh with a >1 model axis this switches to the sharded flatbuf
-    layout + shard_map program (:func:`_fused_shard_map`): TP-sharded
-    leaves stay sharded end to end and the Pallas kernels run per rank.
+    layout + shard_map program: TP-sharded leaves stay sharded end to
+    end.
     """
+    mode = kops.fused_kernel_mode()
+    layout = None
     if specs is not None and topo.model_shards > 1:
         layout = flatbuf.make_layout(
             u_dev, batch_dims=2,
             sharding=shardflat.model_sharding(topo, specs))
-        if layout.shards > 1:
-            return _fused_shard_map(topo, layout, u_dev, delta, None, rho,
-                                    mask, None, None, None)
-    layout = flatbuf.make_layout(u_dev, batch_dims=2)
-    mode = kops.fused_kernel_mode(topo.mesh.size)
+    if layout is None or layout.shards == 1:
+        layout = flatbuf.make_layout(u_dev, batch_dims=2)
+    if _per_rank(topo, layout, mode):
+        return _fused_shard_map(topo, layout, u_dev, delta, None, rho,
+                                mask, None, None, None)
     if mode in ("pallas", "interpret"):
         u_buf, d_buf = _fused_kernel_bufs(layout, u_dev, delta, None, rho)
         vote = kops.fused_sign_vote_flat(
@@ -546,11 +559,11 @@ def fused_sign_vote_update(topo: Topology, layout: flatbuf.FlatLayout,
     shard_map program (:func:`_fused_shard_map`): the buffer stays
     model-axis sharded for the whole read-modify-write.
     """
-    if layout.shards > 1:
+    mode = kops.fused_kernel_mode()
+    if _per_rank(topo, layout, mode):
         new_v = _fused_shard_map(topo, layout, u_dev, None, delta_buf,
                                  rho, mask, v_buf, mu, mu_static)
         return topo.constrain(new_v, shardflat.buf_spec(topo, layout, 1))
-    mode = kops.fused_kernel_mode(topo.mesh.size)
     if mode in ("pallas", "interpret"):
         u_buf, d_buf = _fused_kernel_bufs(layout, u_dev, None, delta_buf,
                                           rho)
@@ -659,8 +672,9 @@ def fused_sign_tally_accumulate(topo: Topology, layout: flatbuf.FlatLayout,
     delta_buf: optional DC correction ([P, *leaf] tree or [P, n_pad]
     buffer), fused pre-sign exactly like :func:`fused_sign_vote`;
     weights: [P, D] integer vote weights of this client this round;
-    tally: [P, D, n_pad] signed tally (``tally_dtype(weight_bound)``).
-    Returns the updated tally.  No collective runs here -- the data
+    tally: [P, D, n_pad] or lane-row [P, D, n_pad/128, 128] signed
+    tally (``tally_dtype(weight_bound)``).  Returns the updated tally
+    in the same shape.  No collective runs here -- the data
     exchange of the streamed sweep happens once per local step in
     :func:`fused_tally_finish`.
 
@@ -672,10 +686,10 @@ def fused_sign_tally_accumulate(topo: Topology, layout: flatbuf.FlatLayout,
     > 1``) runs the same per-rank program under shard_map on each
     rank's bucket.
     """
-    if layout.shards > 1:
+    mode = kops.fused_kernel_mode()
+    if _per_rank(topo, layout, mode):
         return _tally_acc_shard_map(topo, layout, u_dev, delta_tree,
                                     delta_buf, rho, weights, tally)
-    mode = kops.fused_kernel_mode(topo.mesh.size)
     if mode in ("pallas", "interpret"):
         u_buf, d_buf = _fused_kernel_bufs(layout, u_dev, delta_tree,
                                           delta_buf, rho)
@@ -702,7 +716,7 @@ def _tally_acc_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
     u_dev = flatbuf.pad_tree(layout, u_dev, 2)
     if delta_tree is not None:
         delta_tree = flatbuf.pad_tree(layout, delta_tree, 1)
-    mode = kops.fused_kernel_mode(topo.mesh.size, shard_mapped=True)
+    mode = kops.fused_kernel_mode()
     use_kernel = mode in ("pallas", "interpret")
     interpret = mode == "interpret"
 
@@ -735,9 +749,9 @@ def _tally_acc_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
                                   rho=rho, delta_batch_dims=1)
         return tally_accumulate_words(words, w_l, t_l)
 
-    fn = shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
-                   out_specs=shardflat.buf_spec(topo, layout, 2),
-                   check_rep=False)
+    fn = jax.shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
+                       out_specs=shardflat.buf_spec(topo, layout, 2),
+                       check_vma=False)
     return fn(*args)
 
 
@@ -748,8 +762,9 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
     per-device tallies over ``data`` ONCE per local step, defer-threshold
     into the vote, and optionally apply ``v <- v - mu*vote``.
 
-    tally: [P, D, n_pad] accumulated signed tallies (all K clients
-    folded in); n_eff: [P] int32 participating weight sum of the round.
+    tally: [P, D, n_pad] or lane-row [P, D, n_pad/128, 128]
+    accumulated signed tallies (all K clients folded in); n_eff: [P]
+    int32 participating weight sum of the round.
     With ``v_buf`` (flat state) returns the updated [P, n_pad] buffer;
     without it returns the vote as a [P, *leaf] int8 pytree -- mirroring
     :func:`fused_sign_vote_update` / :func:`fused_sign_vote`.
@@ -775,8 +790,8 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
             # the ONE per-step collective of the streamed sweep
             t = jax.lax.all_gather(kw["t"], topo.data_axis, axis=1,
                                    tiled=True)
-            ts = jnp.sum(t.astype(jnp.int32), axis=1)          # [1, n_l]
-            vote = tally_vote(ts, kw["n"])
+            ts = jnp.sum(t.astype(jnp.int32), axis=1)
+            vote = tally_vote(ts, kw["n"]).reshape(ts.shape[0], -1)
             if want_update:
                 return kw["v"] - kw["mu"] * vote.astype(kw["v"].dtype)
             return flatbuf.unflatten_tree(bucket, vote, batch_dims=1,
@@ -784,8 +799,9 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
 
         out_specs = (shardflat.buf_spec(topo, layout, 1) if want_update
                      else shardflat.leaf_specs(topo, layout, 1))
-        fn = shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(program, mesh=topo.mesh,
+                           in_specs=tuple(in_specs), out_specs=out_specs,
+                           check_vma=False)
         out = fn(*args)
         if want_update:
             return topo.constrain(out, shardflat.buf_spec(topo, layout, 1))
@@ -793,8 +809,8 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
     # the device->edge uplink: gather the int tallies over 'data'
     t = topo.constrain(tally, P(topo.pod_axis, topo.data_axis, None))
     t = topo.constrain(t, P(topo.pod_axis, None, None))
-    ts = jnp.sum(t.astype(jnp.int32), axis=1)                  # [P, n_pad]
-    vote = tally_vote(ts, n_eff)
+    ts = jnp.sum(t.astype(jnp.int32), axis=1)
+    vote = tally_vote(ts, n_eff).reshape(ts.shape[0], -1)      # [P, n_pad]
     vote = topo.constrain(vote, P(topo.pod_axis, None))
     if v_buf is None:
         return flatbuf.unflatten_tree(layout, vote, batch_dims=1,

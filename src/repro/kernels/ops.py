@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles arbitrary pytree/shape inputs (flatten -> pad -> 2D view ->
-kernel -> unpad) and **backend-detects** instead of hardcoding a mode:
+Handles arbitrary pytree/shape inputs (flatten -> pad -> lane-row view
+-> kernel -> unpad) and **backend-detects** instead of hardcoding a
+mode:
 
   * on TPU (``jax.default_backend() == "tpu"``) the compiled Pallas
     kernels run by default (``interpret=False``);
@@ -12,10 +13,12 @@ kernel -> unpad) and **backend-detects** instead of hardcoding a mode:
 Pass ``use_pallas=``/``interpret=`` explicitly to override (the kernel
 tests force ``use_pallas=True, interpret=True`` on CPU).
 
-2D views are layout-cached: the (rows, pad) arithmetic for a given
-(n, block) is computed once per process, and inputs whose flat size is
-already block-aligned (everything produced by ``core.flatbuf``) are pure
-reshape views -- no concatenate, no pad.
+The kernels see [slabs, n/128, 128] lane rows (``kernels.sign_pack``):
+for ``core.flatbuf`` buffers (n a multiple of 4096) that is a pure
+reshape -- for f32 the very bytes of the flat buffer -- and the packed
+words come back in the kernels' lane-plane bit order, which only
+``fused_vote_update_words`` reads.  The (rows, pad) arithmetic of the
+any-shape wrappers is computed once per process (``_pad_layout``).
 
 ``fused_sign_vote_flat`` is the vote-only local compute of the fused
 transport; ``fused_vote_update_flat`` (state_layout="flat") additionally
@@ -47,7 +50,9 @@ import jax.numpy as jnp
 from repro.kernels import ref, sign_pack as _sp, tally_acc as _ta
 from repro.kernels import ternary_quant as _tq, vote_update as _vu
 
-PACK = 32
+PACK = _sp.PACK
+LANES = _sp.LANES
+TILE = _sp.TILE
 
 
 # ---------------------------------------------------------------------------
@@ -67,29 +72,23 @@ def _resolve(use_pallas: bool | None, interpret: bool | None):
     return use_pallas, interpret
 
 
-def fused_kernel_mode(mesh_size: int, shard_mapped: bool = False) -> str:
+def fused_kernel_mode() -> str:
     """How the fused flat-buffer transport should run its local compute.
 
-    Returns ``"pallas"`` (compiled), ``"interpret"`` or ``"jnp"``.  The
-    Pallas kernels are single-device programs; outside ``shard_map``
-    they only engage when the mesh has one device (single-chip runs /
-    per-host simulation) and multi-device GSPMD meshes take the
-    pure-jnp path, whose collectives partition correctly.  With
-    ``shard_mapped=True`` the caller is building a per-rank shard_map
-    program -- every rank is a single device there, so the compiled
-    kernels engage on TPU at ANY mesh size (this is the multi-chip
-    fused path of ``core.votes``).  ``REPRO_FUSED_PALLAS`` overrides:
-    ``off`` forces jnp, ``interpret`` forces interpret-mode Pallas
-    (used by tests to exercise the kernel route on CPU).
+    Returns ``"pallas"`` (compiled), ``"interpret"`` or ``"jnp"``: the
+    compiled kernels on TPU, the pure-jnp route elsewhere.  The kernels
+    are single-device programs, so on a multi-device mesh ``core.votes``
+    runs them per rank inside its ``shard_map`` program (every rank is
+    one device) whatever the model-axis extent.  ``REPRO_FUSED_PALLAS``
+    overrides: ``off`` forces jnp, ``interpret`` forces interpret-mode
+    Pallas (used by tests to exercise the kernel route on CPU).
     """
     env = os.environ.get("REPRO_FUSED_PALLAS", "auto").lower()
     if env in ("0", "off", "jnp"):
         return "jnp"
     if env == "interpret":
         return "interpret"
-    if (shard_mapped or mesh_size == 1) and on_tpu():
-        return "pallas"
-    return "jnp"
+    return "pallas" if on_tpu() else "jnp"
 
 
 # ---------------------------------------------------------------------------
@@ -118,34 +117,32 @@ def _to_2d(x: jax.Array, block_r: int, block_c: int):
     return flat.reshape(rows, block_c), n
 
 
-@functools.lru_cache(maxsize=None)
-def _row_block(rows: int, block_r: int) -> int:
-    """Largest power-of-two divisor of ``rows`` that is <= block_r."""
-    b = 1
-    while b < block_r and rows % (2 * b) == 0:
-        b *= 2
-    return b
-
-
 # ---------------------------------------------------------------------------
 # N-d kernel wrappers
 # ---------------------------------------------------------------------------
 
+def _lane_rows(x: jax.Array):
+    """Any-shape x -> its [1, L, 128] lane-row view (zero-padded to a
+    whole 4096-coordinate word row), plus the real coordinate count."""
+    x2, n = _to_2d(x, 1, TILE)
+    return x2.reshape(1, -1, LANES), n
+
+
 def sign_pack_nd(g: jax.Array, delta: jax.Array | None = None,
                  rho: float = 0.0, *, use_pallas: bool | None = None,
-                 interpret: bool | None = None,
-                 block_r: int = _sp.BLOCK_R, block_c: int = _sp.BLOCK_C):
-    """Any-shape g (+delta) -> (packed [n_words] uint32, n_coords)."""
+                 interpret: bool | None = None, block_r: int = _sp.BLOCK_R):
+    """Any-shape g (+delta) -> (packed [n_words] uint32, n_coords).
+
+    Words are in the kernels' lane-plane bit order
+    (``kernels.sign_pack``); ``vote_update_nd`` consumes them."""
     use_pallas, interpret = _resolve(use_pallas, interpret)
-    g2, n = _to_2d(g, block_r, block_c)
-    d2 = None
-    if delta is not None:
-        d2, _ = _to_2d(delta.astype(g.dtype), block_r, block_c)
+    g3, n = _lane_rows(g)
+    d3 = None if delta is None else _lane_rows(delta.astype(g.dtype))[0]
     if use_pallas:
-        packed = _sp.sign_pack(g2, d2, rho, block_r=block_r,
-                               block_c=block_c, interpret=interpret)
+        packed = _sp.sign_pack(g3, d3, rho, block_r=block_r,
+                               interpret=interpret)
     else:
-        packed = ref.sign_pack_ref(g2, d2, rho)
+        packed = ref.sign_pack_ref(g3, d3, rho)
     return packed.reshape(-1), n
 
 
@@ -153,19 +150,19 @@ def vote_update_nd(packed_rows: jax.Array, v: jax.Array,
                    mask: jax.Array | None = None, *, mu: float,
                    use_pallas: bool | None = None,
                    interpret: bool | None = None,
-                   block_r: int = _vu.BLOCK_R, block_c: int = _vu.BLOCK_C):
+                   block_r: int = _vu.BLOCK_R):
     """packed_rows: [K, n_words] (from sign_pack_nd on each device);
-    v: any-shape model tensor.  Returns updated v."""
+    v: any-shape model tensor; mask: optional [K].  Returns updated v."""
     use_pallas, interpret = _resolve(use_pallas, interpret)
     k = packed_rows.shape[0]
-    v2, n = _to_2d(v, block_r, block_c)
-    r, c = v2.shape
-    packed = packed_rows.reshape(k, r, c // PACK)
+    v3, n = _lane_rows(v)
+    packed = packed_rows.reshape(1, k, -1, LANES)
+    m = None if mask is None else mask[None]
     if use_pallas:
-        out = _vu.vote_update(packed, v2, mask, mu=mu, block_r=block_r,
-                              block_c=block_c, interpret=interpret)
+        out = _vu.vote_update(packed, v3, m, mu=mu, block_r=block_r,
+                              interpret=interpret)
     else:
-        out = ref.vote_update_ref(packed, v2, mu, mask)
+        out = ref.vote_update_ref(packed, v3, mu, m)
     return out.reshape(-1)[:n].reshape(v.shape)
 
 
@@ -191,6 +188,20 @@ def ternary_quant_nd(x: jax.Array, rng: jax.Array, *,
 # Fused flat-buffer transport (local compute of core.votes "fused")
 # ---------------------------------------------------------------------------
 
+def _slabs(u_buf: jax.Array, d_buf: jax.Array | None, rho: float):
+    """[P, D, n] directions (+ [P, n] correction) -> the kernels'
+    [P*D, n/128, 128] lane-row voter slabs (+ [P, n/128, 128] shared
+    slabs).  Pure reshapes: for f32 the (8, 128)-tiled view is the flat
+    buffer's own byte order."""
+    p, d, n = u_buf.shape
+    assert n % TILE == 0, (n, TILE)
+    g3 = u_buf.reshape(p * d, n // LANES, LANES)
+    d3 = None
+    if d_buf is not None and rho:
+        d3 = d_buf.astype(u_buf.dtype).reshape(p, n // LANES, LANES)
+    return g3, d3
+
+
 def fused_pack_flat(u_buf: jax.Array, d_buf: jax.Array | None,
                     rho: float, *, interpret: bool) -> jax.Array:
     """Device-side half of the fused transport: flat floats -> packed words.
@@ -199,14 +210,16 @@ def fused_pack_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     d_buf: [P, n_pad] correction or None (the caller only folds the DC
     correction here for all-f32 trees -- the kernel adds in f32, which
     is exact iff the reference arithmetic is f32 too).  Returns the
-    1-bit uplink payload [P, D, n_pad/32] uint32 via ONE ``sign_pack``
-    sweep over all P*D rows (delta re-read per voter through its
-    BlockSpec, never broadcast-copied).  This is the pre-gather half the
-    multi-chip shard_map program runs per rank before the data-axis
+    1-bit uplink payload [P, D, n_pad/32] uint32 (lane-plane bit order,
+    read only by :func:`fused_vote_update_words`) via ONE ``sign_pack``
+    sweep over all P*D voter slabs (delta re-read per voter through its
+    BlockSpec, never broadcast-copied).  This is the pre-gather half
+    the multi-chip shard_map program runs per rank before the data-axis
     all-gather of the words (``core.votes``).
     """
     p, d, n = u_buf.shape
-    packed, _, _ = _sign_pack_slabs(u_buf, d_buf, rho, interpret)
+    g3, d3 = _slabs(u_buf, d_buf, rho)
+    packed = _sp.sign_pack(g3, d3, rho, interpret=interpret)
     return packed.reshape(p, d, n // PACK)
 
 
@@ -215,34 +228,24 @@ def fused_vote_update_words(words: jax.Array, v_buf: jax.Array | None,
                             interpret: bool) -> jax.Array:
     """Edge-side half: packed voter words -> vote (+ optional update).
 
-    words: [P, D, n_words] uint32 (all D voters' payloads, e.g. after
-    the data-axis gather; D may be the merged virtual-client axis D*K);
-    v_buf: [P, n_pad] float master buffer, or None to compute a pure
-    vote (v = 0, mu = -1 makes the fused update emit exactly
-    ``MajorityVote``); mask: [P, D] voter mask, nonnegative integer
-    vote weights (weighted popcount; an empty quorum abstains and
-    leaves v untouched), or None.
-    ONE ``vote_update`` read-modify-write per pod over the whole-model
+    words: [P, D, n_words] uint32 from :func:`fused_pack_flat` (all D
+    voters' payloads, e.g. after the data-axis gather; D may be the
+    merged virtual-client axis D*K); v_buf: [P, n_pad] float master
+    buffer, or None to compute a pure vote (v = 0, mu = -1 makes the
+    fused update emit exactly ``MajorityVote``); mask: [P, D] voter
+    mask, nonnegative integer vote weights (weighted popcount; an empty
+    quorum abstains and leaves v untouched), or None.
+    ONE ``vote_update`` read-modify-write over every pod's whole-model
     packed-word buffer.
     """
     p, d, w = words.shape
     n = w * PACK
-    block_c = _vu.BLOCK_C
-    rows = n // block_c
-    assert n % block_c == 0, (n, block_c)
-    packed = words.reshape(p, d, rows, block_c // PACK)
-    v2 = None if v_buf is None else v_buf.reshape(p, rows, block_c)
-    zeros = (jnp.zeros((rows, block_c), jnp.float32) if v_buf is None
-             else None)
-    brv = _row_block(rows, _vu.BLOCK_R)
-    out = []
-    for q in range(p):                     # P is small and static
-        m_q = mask[q] if mask is not None else None
-        out.append(_vu.vote_update(packed[q],
-                                   zeros if v2 is None else v2[q],
-                                   m_q, mu=mu, block_r=brv,
-                                   block_c=block_c, interpret=interpret))
-    return jnp.stack(out).reshape(p, n)
+    assert n % TILE == 0, (n, TILE)
+    packed = words.reshape(p, d, n // TILE, LANES)
+    v3 = (jnp.zeros((p, n // LANES, LANES), jnp.float32) if v_buf is None
+          else v_buf.reshape(p, n // LANES, LANES))
+    out = _vu.vote_update(packed, v3, mask, mu=mu, interpret=interpret)
+    return out.reshape(p, n)
 
 
 def fused_sign_vote_flat(u_buf: jax.Array, d_buf: jax.Array | None,
@@ -260,23 +263,6 @@ def fused_sign_vote_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     return vote.astype(jnp.int8)
 
 
-def _sign_pack_slabs(u_buf: jax.Array, d_buf: jax.Array | None, rho: float,
-                     interpret: bool):
-    """[P, D, n] float -> ([P, D, rows, words/row] packed, rows, block_c)."""
-    p, d, n = u_buf.shape
-    block_c = _sp.BLOCK_C
-    rows = n // block_c
-    assert n % block_c == 0, (n, block_c)
-    g2 = u_buf.reshape(p * d * rows, block_c)
-    br = _row_block(rows, _sp.BLOCK_R)
-    d2 = None
-    if d_buf is not None and rho:
-        d2 = d_buf.astype(u_buf.dtype).reshape(p * rows, block_c)
-    packed = _sp.sign_pack(g2, d2, rho, block_r=br, block_c=block_c,
-                           interpret=interpret, slab_rows=rows)
-    return packed.reshape(p, d, rows, block_c // PACK), rows, block_c
-
-
 def fused_tally_acc_flat(u_buf: jax.Array, d_buf: jax.Array | None,
                          rho: float, weights: jax.Array,
                          tally: jax.Array, *, interpret: bool) -> jax.Array:
@@ -286,28 +272,20 @@ def fused_tally_acc_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     client (physical device axis, NOT the merged D*K); d_buf: [P, n_pad]
     correction or None (same fold rules as ``fused_pack_flat``);
     weights: [P, D] integer vote weights of this client; tally:
-    [P, D, n_pad] signed tally (int8/int16/int32 per
-    ``core.votes.tally_dtype``).  ONE ``tally_acc`` read-modify-write
-    sweep over all P*D rows -- the client's sign plane never reaches
-    HBM, and the delta block is re-read per voter through its BlockSpec
-    exactly like ``fused_pack_flat``.
+    [P, D, n_pad] or lane-row [P, D, n_pad/128, 128] signed tally
+    (int8/int16/int32 per ``core.votes.tally_dtype``), returned in the
+    same shape.  ONE ``tally_acc`` read-modify-write
+    sweep over all P*D voter slabs -- the client's sign plane never
+    reaches HBM, and the delta block is re-read per voter through its
+    BlockSpec exactly like ``fused_pack_flat``.
     """
     p, d, n = u_buf.shape
-    assert tally.shape == (p, d, n), (tally.shape, u_buf.shape)
-    block_c = _ta.BLOCK_C
-    rows = n // block_c
-    assert n % block_c == 0, (n, block_c)
-    g2 = u_buf.reshape(p * d * rows, block_c)
-    t2 = tally.reshape(p * d * rows, block_c)
-    d2 = None
-    if d_buf is not None and rho:
-        d2 = d_buf.astype(u_buf.dtype).reshape(p * rows, block_c)
-    br = _row_block(rows, _ta.BLOCK_R)
-    w2 = weights.reshape(p * d, 1)
-    out = _ta.tally_acc(g2, d2, w2, t2, rho=rho, block_r=br,
-                        block_c=block_c, interpret=interpret,
-                        slab_rows=rows)
-    return out.reshape(p, d, n)
+    assert tally.size == u_buf.size, (tally.shape, u_buf.shape)
+    g3, d3 = _slabs(u_buf, d_buf, rho)
+    out = _ta.tally_acc(g3, d3, weights.reshape(p * d),
+                        tally.reshape(g3.shape), rho=rho,
+                        interpret=interpret)
+    return out.reshape(tally.shape)
 
 
 def fused_vote_update_flat(u_buf: jax.Array, d_buf: jax.Array | None,
@@ -319,10 +297,10 @@ def fused_vote_update_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     u_buf: [P, D, n_pad] float pre-sign directions; d_buf: [P, n_pad]
     correction or None (same fold rules as ``fused_sign_vote_flat``);
     v_buf: [P, n_pad] master buffer; mu: static step size.  One
-    ``sign_pack`` sweep over all P*D rows, then exactly ONE
-    ``vote_update`` read-modify-write per pod over the whole-model
-    packed-word buffer -- the vote never materializes, the update is the
-    kernel's single HBM pass over v (aliased in place when compiled).
+    ``sign_pack`` sweep over all P*D voter slabs, then exactly ONE
+    ``vote_update`` read-modify-write over the whole-model packed-word
+    buffer -- the vote never materializes, the update is the kernel's
+    single HBM pass over v (aliased in place when compiled).
     """
     p, d, n = u_buf.shape
     assert v_buf.shape == (p, n), (v_buf.shape, (p, n))

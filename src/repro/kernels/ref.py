@@ -10,29 +10,66 @@ import jax.numpy as jnp
 
 from repro.core import signs
 
+PACK = signs.PACK_WIDTH
+TILE = 4096                      # coordinates per 128-word row
+
+
+def pack_planes(s: jax.Array) -> jax.Array:
+    """[..., n] {-1,+1} signs (n % 4096 == 0) -> [..., n/32] uint32 words
+    in the kernels' lane-plane bit order: bit j of word w is the sign of
+    coordinate ``w + 128*j`` of the w-th 4096-wide row (1 = +1)."""
+    *lead, c = s.shape
+    wpb = TILE // PACK
+    bits = (s >= 0).astype(jnp.uint32).reshape(*lead, c // TILE, PACK,
+                                                wpb)
+    shifts = jnp.arange(PACK, dtype=jnp.uint32)[:, None]
+    words = jax.lax.reduce(bits << shifts, jnp.uint32(0),
+                           jax.lax.bitwise_or, (bits.ndim - 2,))
+    return words.reshape(*lead, c // PACK)
+
+
+def unpack_planes(words: jax.Array) -> jax.Array:
+    """Inverse of :func:`pack_planes`: [..., W] words -> [..., W*32]
+    int8 signs in coordinate order."""
+    *lead, w = words.shape
+    wpb = TILE // PACK
+    blocks = words.reshape(*lead, w // wpb, 1, wpb)
+    shifts = jnp.arange(PACK, dtype=jnp.uint32)[:, None]
+    bits = (blocks >> shifts) & jnp.uint32(1)           # [..., b, 32, wpb]
+    return jnp.where(bits == 1, jnp.int8(1), jnp.int8(-1)).reshape(
+        *lead, w * PACK)
+
 
 def sign_pack_ref(g: jax.Array, delta: jax.Array | None, rho: float
                   ) -> jax.Array:
-    """(g, delta) -> packed uint32 words; g/delta: [R, C], C % 32 == 0."""
+    """(g, delta) -> packed uint32 words in the lane-plane bit order.
+
+    g: [S, L, 128] voter slabs; delta: optional [S/reps, L, 128]
+    correction shared by ``reps`` consecutive slabs; returns
+    [S, L/32, 128] (``kernels.sign_pack``)."""
     u = g.astype(jnp.float32)
     if delta is not None and rho:
-        u = u + rho * delta.astype(jnp.float32)
-    return signs.pack_signs(signs.sgn(u))
+        reps = g.shape[0] // delta.shape[0]
+        u = u + rho * jnp.repeat(delta.astype(jnp.float32), reps, axis=0)
+    s, rows, lanes = g.shape
+    words = pack_planes(signs.sgn(u).reshape(s, rows * lanes))
+    return words.reshape(s, rows // PACK, lanes)
 
 
 def vote_update_ref(packed: jax.Array, v: jax.Array, mu: float,
                     mask: jax.Array | None = None) -> jax.Array:
-    """packed: [K, R, C/32] uint32; v: [R, C] f32 -> v - mu * vote.
+    """packed: [P, K, L/32, 128] lane-plane words; v: [P, L, 128] ->
+    v - mu * vote.
 
-    mask: optional [K] voter mask or integer vote weights -- the
+    mask: optional [P, K] voter mask or integer vote weights -- the
     weighted-popcount / empty-quorum-abstains conventions come from
-    ``signs.majority_vote_packed`` (matching the Pallas kernel)."""
-    k, r, w = packed.shape
-    c = v.shape[-1]
-    vote = jax.vmap(
-        lambda col: signs.majority_vote_packed(col, c, mask),
-        in_axes=1, out_axes=0)(packed)          # [R, C]
-    return v - mu * vote.astype(v.dtype)
+    ``signs.majority_vote`` (matching the Pallas kernel)."""
+    p, k = packed.shape[:2]
+    s = unpack_planes(packed.reshape(p, k, -1))         # [P, K, n]
+    m = None if mask is None else mask[:, :, None]
+    vote = signs.majority_vote(s, m, axis=1).reshape(v.shape)
+    return (v.astype(jnp.float32) - mu * vote.astype(jnp.float32)
+            ).astype(v.dtype)
 
 
 def tally_acc_ref(u_buf: jax.Array, d_buf: jax.Array | None, rho: float,

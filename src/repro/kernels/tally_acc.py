@@ -16,12 +16,13 @@ sign threshold until after the loop (``core.votes.tally_vote``), where
 exactly -- integer arithmetic, so the streamed trajectory is bitwise
 identical to the merged-axis transports.
 
-Tiling: grid over [R/BR, C/BC] like ``sign_pack``; per step the kernel
-reads a (BR, BC) f32 block of g (+ the shared correction block via the
-same slab-row BlockSpec trick) and read-modify-writes the (BR, BC)
-tally block in place (aliased when compiled).  The per-voter weight
-arrives as a [n_slabs, 1] int32 array indexed per row-block through its
-BlockSpec -- no scalar re-tracing per client.
+Tiling: grid over [S, cdiv(L, 32*BR)] on the [S, L, 128] lane-row
+voter slabs of ``sign_pack``; per step the kernel reads a (32*BR, 128)
+f32 block of g (+ the shared correction block, re-read per voter
+through its BlockSpec) and read-modify-writes the same block of the
+tally in place (aliased when compiled).  The per-voter weights arrive
+as one [S] int32 array in SMEM, read as the scalar of the block's slab
+-- no scalar re-tracing per client.
 
 Single-device program: on multi-chip meshes it runs per-rank inside the
 streamed fused transport's ``shard_map`` program (``core.votes``) on the
@@ -35,76 +36,67 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_R = 64
-BLOCK_C = 4096
+from repro.kernels.sign_pack import BLOCK_R, LANES, PACK, row_block
 
 
-def _tally_acc_kernel(g_ref, d_ref, w_ref, t_ref, o_ref, *, rho: float):
+def _tally_acc_kernel(w_ref, g_ref, d_ref, t_ref, o_ref, *, rho: float):
     g = g_ref[...].astype(jnp.float32)
     if d_ref is not None:
         g = g + rho * d_ref[...].astype(jnp.float32)
     s = jnp.where(g >= 0, jnp.int32(1), jnp.int32(-1))
-    w = w_ref[0, 0]                                 # this slab's weight
+    w = w_ref[pl.program_id(0)]                     # this slab's weight
     o_ref[...] = (t_ref[...].astype(jnp.int32) + w * s).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("rho", "block_r", "block_c",
-                                    "interpret", "slab_rows"))
+                   static_argnames=("rho", "block_r", "interpret"))
 def tally_acc(g: jax.Array, delta: jax.Array | None, w: jax.Array,
               tally: jax.Array, *, rho: float = 0.0,
-              block_r: int = BLOCK_R, block_c: int = BLOCK_C,
-              interpret: bool = False,
-              slab_rows: int | None = None) -> jax.Array:
-    """g, tally: [R, C] (R % block_r == 0, C % block_c == 0); w:
-    [R/slab_rows, 1] int32 per-voter weights (one weight per contiguous
-    ``slab_rows``-row voter slab; ``slab_rows=None`` means one voter owns
-    all R rows); delta: optional [R/replicas, C] shared correction,
-    re-read per voter through its BlockSpec exactly like ``sign_pack``'s
-    ``slab_rows`` path.  Returns the updated tally (int8/int16/int32),
-    aliased over the input when compiled.
+              block_r: int = BLOCK_R, interpret: bool = False) -> jax.Array:
+    """g, tally: [S, L, 128] lane-row voter slabs (L % 32 == 0); w: [S]
+    int32 per-voter weights; delta: optional [S/reps, L, 128] correction
+    shared by ``reps`` consecutive slabs, re-read per voter through its
+    BlockSpec exactly like ``sign_pack``.  Returns the updated tally
+    (int8/int16/int32), aliased over the input when compiled.
     """
-    r, c = g.shape
-    assert r % block_r == 0 and c % block_c == 0, (g.shape, block_r, block_c)
-    assert tally.shape == (r, c), (tally.shape, g.shape)
-    slab = r if slab_rows is None else slab_rows
-    assert slab % block_r == 0 and r % slab == 0, (slab, block_r, r)
-    rb = slab // block_r                   # row blocks per voter slab
-    assert w.shape == (r // slab, 1), (w.shape, r, slab)
-    grid = (r // block_r, c // block_c)
+    s, rows, lanes = g.shape
+    assert lanes == LANES and rows % PACK == 0, g.shape
+    assert tally.shape == g.shape, (tally.shape, g.shape)
+    assert w.shape == (s,), (w.shape, s)
+    br = row_block(rows // PACK, block_r)
+    grid = (s, pl.cdiv(rows // PACK, br))
+    shape = (None, PACK * br, LANES)
+    blk = lambda v, i: (v, i, 0)
 
-    in_specs = [pl.BlockSpec((block_r, block_c), lambda i, j: (i, j))]
-    args = [g]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(shape, blk)]
+    args = [w.astype(jnp.int32), g]
     if delta is not None:
-        if delta.shape[0] == r:
-            dmap = lambda i, j: (i, j)
-        else:
-            assert r % delta.shape[0] == 0, (r, delta.shape)
-            reps = r // delta.shape[0]     # voters sharing each slab
-            dmap = lambda i, j: ((i // (reps * rb)) * rb + i % rb, j)
-        in_specs.append(pl.BlockSpec((block_r, block_c), dmap))
+        assert delta.shape[1:] == g.shape[1:] and s % delta.shape[0] == 0, (
+            delta.shape, g.shape)
+        reps = s // delta.shape[0]             # voters sharing each slab
+        in_specs.append(pl.BlockSpec(shape, lambda v, i: (v // reps, i, 0)))
         args.append(delta)
         kernel = functools.partial(_tally_acc_kernel, rho=rho)
     else:
         kernel = functools.partial(
-            lambda g_ref, w_ref, t_ref, o_ref, *, rho: _tally_acc_kernel(
-                g_ref, None, w_ref, t_ref, o_ref, rho=rho), rho=rho)
-    in_specs.append(pl.BlockSpec((1, 1), lambda i, j, _rb=rb: (i // _rb, 0)))
-    args.append(w.astype(jnp.int32))
-    in_specs.append(pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)))
+            lambda w_ref, g_ref, t_ref, o_ref, *, rho: _tally_acc_kernel(
+                w_ref, g_ref, None, t_ref, o_ref, rho=rho), rho=rho)
+    in_specs.append(pl.BlockSpec(shape, blk))
     args.append(tally)
 
     # the tally aliases in place: a true read-modify-write (one HBM pass
     # over the tally when the caller donates it).  Interpret mode keeps
     # out-of-place semantics -- identical values either way.
-    t_index = len(args) - 1
-    alias = {} if interpret else {"input_output_aliases": {t_index: 0}}
+    alias = ({} if interpret
+             else {"input_output_aliases": {len(args) - 1: 0}})
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec(shape, blk),
         out_shape=jax.ShapeDtypeStruct(tally.shape, tally.dtype),
         interpret=interpret,
         **alias,

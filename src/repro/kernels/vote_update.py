@@ -11,15 +11,21 @@ The voter ``mask`` generalizes to nonnegative integer vote weights (the
 weight in the int32 tally, the tie rule compares against the
 participating weight sum, and an edge whose whole quorum abstains (all
 weights 0) votes 0 -- the read-modify-write then leaves v unchanged.
+The [P, K] weights live in SMEM and are read as scalars.
 
-Tiling: grid over [R/BR, C/BC]; per step the kernel reads a (K, BR, BC/32)
-uint32 slab + a (BR, BC) f32 block of v (VMEM ~2 MB at K=16).
+Tiling: grid over [P, cdiv(L/32, BR)] on the [P, L, 128] lane-row view
+of v (``kernels.sign_pack``); per step the kernel reads a (K, BR, 128)
+uint32 slab + a (32*BR, 128) block of v.  Words are in the lane-plane
+bit order of ``kernels.sign_pack``: bit plane j of a (BR, 128) word
+block is the sublane-strided row set ``32*r + j`` of the v block, so
+unpacking is a shift and a mask per plane -- no reshape, and only one
+(BR, 128) int32 tally is live at a time.
 
 Single-device program: on multi-chip meshes it runs per-rank inside the
 fused transport's ``shard_map`` program (``core.votes``) on the rank's
-model-axis bucket of the flat buffer, consuming the K uplink payloads
-gathered over the data axis -- the vote never sees (and the mesh never
-materializes) an unsharded bit tensor.
+bucket of the flat buffer, consuming the K uplink payloads gathered over
+the data axis -- the vote never sees (and the mesh never materializes)
+an unsharded bit tensor.
 """
 from __future__ import annotations
 
@@ -28,71 +34,72 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-PACK = 32
-BLOCK_R = 64
-BLOCK_C = 4096
+from repro.kernels.sign_pack import BLOCK_R, LANES, PACK, row_block
 
 
-def _vote_update_kernel(p_ref, v_ref, m_ref, o_ref, *, mu: float,
-                        n_voters: int):
-    words = p_ref[...]                              # [K, BR, BC/32] uint32
-    k, br, wpb = words.shape
-    shifts = jnp.arange(PACK, dtype=jnp.uint32)
-    bits = ((words[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-    if m_ref is not None:
-        m = m_ref[...].astype(jnp.int32)            # [K] mask or weights
-        pos = jnp.sum(bits * m[:, None, None, None], axis=0)
-        n_eff = jnp.sum(m)
-    else:
-        pos = jnp.sum(bits, axis=0)                 # [BR, BC/32, 32]
-        n_eff = n_voters
-    vote = jnp.where(2 * pos >= n_eff, 1.0, -1.0).astype(jnp.float32)
-    if m_ref is not None:   # empty quorum abstains: v is left unchanged
-        vote = jnp.where(n_eff > 0, vote, 0.0).astype(jnp.float32)
-    vote = vote.reshape(br, wpb * PACK)
-    o_ref[...] = (v_ref[...].astype(jnp.float32) - mu * vote
-                  ).astype(o_ref.dtype)
+def _vote_update_kernel(m_ref, p_ref, v_ref, o_ref, *, mu: float):
+    k, br, _ = p_ref.shape                          # (K, BR, 128) words
+    q = pl.program_id(0)
+    weight = (lambda i: 1) if m_ref is None else (lambda i: m_ref[q, i])
+    n_eff = (k if m_ref is None else jax.lax.fori_loop(
+        0, k, lambda i, acc: acc + weight(i), jnp.int32(0)))
+    for j in range(PACK):
+        def tally(i, pos, j=j):
+            bit = ((p_ref[i] >> jnp.uint32(j)) & jnp.uint32(1)
+                   ).astype(jnp.int32)
+            return pos + bit * weight(i)
+
+        pos = jax.lax.fori_loop(0, k, tally,
+                                jnp.zeros((br, LANES), jnp.int32))
+        vote = jnp.where(2 * pos >= n_eff, 1.0, -1.0).astype(jnp.float32)
+        if m_ref is not None:   # empty quorum abstains: v is left unchanged
+            vote = jnp.where(n_eff > 0, vote, 0.0).astype(jnp.float32)
+        plane = pl.ds(j, br, stride=PACK)
+        o_ref[plane, :] = (v_ref[plane, :].astype(jnp.float32) - mu * vote
+                           ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("mu", "block_r", "block_c", "interpret"))
+                   static_argnames=("mu", "block_r", "interpret"))
 def vote_update(packed: jax.Array, v: jax.Array,
                 mask: jax.Array | None = None, *, mu: float,
-                block_r: int = BLOCK_R, block_c: int = BLOCK_C,
-                interpret: bool = False) -> jax.Array:
-    """packed: [K, R, C/32] uint32; v: [R, C] float; mask: [K] or None."""
-    k, r, w = packed.shape
-    c = v.shape[-1]
-    assert w * PACK == c and v.shape == (r, c)
-    assert r % block_r == 0 and c % block_c == 0
-    grid = (r // block_r, c // block_c)
-    wpb = block_c // PACK
+                block_r: int = BLOCK_R, interpret: bool = False
+                ) -> jax.Array:
+    """packed: [P, K, L/32, 128] uint32 (lane-plane order); v: [P, L, 128]
+    float; mask: [P, K] voter mask / integer vote weights, or None.
+    Returns the updated v (aliased over the input when compiled)."""
+    p, k, wr, lanes = packed.shape
+    assert lanes == LANES and v.shape == (p, PACK * wr, LANES), (
+        packed.shape, v.shape)
+    br = row_block(wr, block_r)
+    grid = (p, pl.cdiv(wr, br))
+    vblk = pl.BlockSpec((None, PACK * br, LANES), lambda q, i: (q, i, 0))
 
-    in_specs = [
-        pl.BlockSpec((k, block_r, wpb), lambda i, j: (0, i, j)),
-        pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-    ]
+    in_specs = [pl.BlockSpec((None, k, br, LANES), lambda q, i: (q, 0, i, 0)),
+                vblk]
     args = [packed, v]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((k,), lambda i, j: (0,)))
-        args.append(mask.astype(jnp.int32))
-        kernel = functools.partial(_vote_update_kernel, mu=mu, n_voters=k)
+        assert mask.shape == (p, k), (mask.shape, (p, k))
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.insert(0, mask.astype(jnp.int32))
+        kernel = functools.partial(_vote_update_kernel, mu=mu)
     else:
         kernel = functools.partial(
-            lambda p_ref, v_ref, o_ref, *, mu, n_voters: _vote_update_kernel(
-                p_ref, v_ref, None, o_ref, mu=mu, n_voters=n_voters),
-            mu=mu, n_voters=k)
+            lambda p_ref, v_ref, o_ref, *, mu: _vote_update_kernel(
+                None, p_ref, v_ref, o_ref, mu=mu), mu=mu)
 
     # v' aliases v: the kernel is a true read-modify-write (one HBM pass
     # over the model when the caller donates v).  Interpret mode keeps
     # out-of-place semantics -- identical values either way.
-    alias = {} if interpret else {"input_output_aliases": {1: 0}}
+    alias = ({} if interpret
+             else {"input_output_aliases": {len(args) - 1: 0}})
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
+        out_specs=vblk,
         out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
         interpret=interpret,
         **alias,

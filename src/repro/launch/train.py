@@ -4,7 +4,8 @@ Wires together: config -> model -> DC-HierSignSGD step -> synthetic data
 stream -> elastic membership -> async checkpointing -> failure recovery.
 Runs the production configs on a real mesh, and the reduced smoke configs
 on CPU (the integration tests and examples call ``run_training`` with a
-small Topology).
+small Topology).  ``main`` prints the platform it runs on: with no
+accelerator JAX runs it on the CPU.
 
 CLI (reduced-scale CPU run):
   PYTHONPATH=src python -m repro.launch.train --arch gemma3_1b --smoke \
@@ -28,6 +29,7 @@ from repro.core import clients as vclients
 from repro.core import hier, schedule, votes
 from repro.core.topology import Topology, single_device_topology
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.models import build
 from repro.runtime import chaos as chaos_mod
 from repro.runtime import elastic, failures
@@ -49,8 +51,17 @@ class RunCfg:
 
 def run_training(cfg, topo: Topology, algo: hier.AlgoConfig, run: RunCfg,
                  fault_injector: failures.FaultInjector | None = None,
-                 on_metrics: Callable[[int, dict], None] | None = None):
-    """Returns (final_state, history).  Deterministic given seeds."""
+                 on_metrics: Callable[[int, dict], None] | None = None,
+                 on_compiled: Callable[[Any, float], None] | None = None):
+    """Returns (final_state, history).  Deterministic given seeds.
+
+    ``history`` holds one dict per step: step, loss, live share and the
+    step's wall seconds (the first includes compilation).
+    ``on_compiled(compiled, seconds)``, when given, receives the step
+    program compiled ahead of the first step (``jax.stages.Compiled``:
+    its HLO text and memory analysis) and the seconds that took; the
+    loop's dispatch reuses that executable (no second compile).
+    """
     built = build.build_model(cfg, topo)
     init_fn, step_fn = hier.make_hier_step(topo, algo, built.bundle)
     jstep = jax.jit(step_fn, donate_argnums=(0,))
@@ -60,6 +71,7 @@ def run_training(cfg, topo: Topology, algo: hier.AlgoConfig, run: RunCfg,
     # (odd vocab/head extents on a TP mesh) only exist as jit-produced
     # arrays -- eager placement of uneven shardings is unsupported
     state = jax.jit(init_fn)(params, jax.random.PRNGKey(run.seed + 1))
+    del params      # the state owns the masters; free the init tree
 
     stream = synthetic.make_stream(synthetic.LMStreamCfg(
         vocab=cfg.vocab, seq_len=run.seq_len,
@@ -99,13 +111,18 @@ def run_training(cfg, topo: Topology, algo: hier.AlgoConfig, run: RunCfg,
                                    now=float(step))
         arrays = member.weights()
         batch = {"train": stream(step)}
-        t0 = time.time()
-        state, metrics = jstep(state, batch,
-                               jnp.asarray(arrays.edge_weights),
-                               jnp.asarray(arrays.dev_weights),
-                               jnp.asarray(arrays.mask))
+        weights = (jnp.asarray(arrays.edge_weights),
+                   jnp.asarray(arrays.dev_weights), jnp.asarray(arrays.mask))
+        if on_compiled is not None:
+            t0 = time.perf_counter()
+            compiled = jstep.lower(state, batch, *weights).compile()
+            on_compiled(compiled, time.perf_counter() - t0)
+            on_compiled = None
+        t0 = time.perf_counter()
+        state, metrics = jstep(state, batch, *weights)
         loss = float(metrics["loss"])
-        detector.record_step(time.time() - t0)
+        seconds = time.perf_counter() - t0
+        detector.record_step(seconds)
         if fault_injector is not None and fault_injector.nan_due(step):
             loss = float("nan")        # injected numeric blow-up
 
@@ -128,7 +145,8 @@ def run_training(cfg, topo: Topology, algo: hier.AlgoConfig, run: RunCfg,
             continue
 
         history.append({"step": step, "loss": loss,
-                        "live": float(np.mean(member.live))})
+                        "live": float(np.mean(member.live)),
+                        "seconds": seconds})
         if on_metrics:
             on_metrics(step, metrics)
         if run.log_every and step % run.log_every == 0:
@@ -211,6 +229,7 @@ def main():
     ap.add_argument("--multi_pod", action="store_true",
                     help="use the production 2x16x16 mesh")
     args = ap.parse_args()
+    compile_cache.enable()
 
     # surface the carve constraint and the scenario axes as clean CLI
     # errors instead of jit-time tracebacks (clustered assignment is
@@ -240,6 +259,8 @@ def main():
         topo = mesh_mod.make_topology(multi_pod=True)
     else:
         topo = single_device_topology()
+    dev = topo.mesh.devices.flat[0]
+    print(f"[train] {topo.mesh.size} x {dev.platform} ({dev.device_kind})")
     algo = hier.AlgoConfig(method=args.method, mu=args.mu, rho=args.rho,
                            cloud_period=args.cloud_period,
                            cloud_overlap=args.cloud_overlap,

@@ -8,7 +8,7 @@ import pytest
 from repro.core import signs
 from repro.kernels import ops, ref
 
-BK = dict(block_r=8, block_c=128)
+BK = dict(block_r=8)
 SHAPES = [(257,), (64, 129), (5, 7, 11), (4096,)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
@@ -22,13 +22,17 @@ def test_sign_pack_matches_oracle(shape, dtype, rho):
          if rho else None)
     packed, n = ops.sign_pack_nd(g, d, rho, use_pallas=True,
                                  interpret=True, **BK)
+    assert n == int(np.prod(shape))
+    if not rho:   # no correction, no rounding: the oracle's exact words
+        expect, _ = ops.sign_pack_nd(g, d, rho, use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(packed), np.asarray(expect))
+    # the lane-plane words unpack to sgn(g + rho*d), coordinate for
+    # coordinate (bit j of word w <-> coordinate w + 128*j of its row)
     u = g.astype(jnp.float32)
     if d is not None:
         u = u + rho * d.astype(jnp.float32)
-    expect = signs.pack_signs(signs.sgn(u.reshape(-1)))
-    assert n == int(np.prod(shape))
-    got_bits = np.asarray(signs.unpack_signs(packed[: expect.shape[0]], n))
-    exp_bits = np.asarray(signs.unpack_signs(expect, n))
+    got_bits = np.asarray(ref.unpack_planes(packed))[:n]
+    exp_bits = np.asarray(signs.sgn(u.reshape(-1)))
     mism = np.where(got_bits != exp_bits)[0]
     # FMA contraction may flip the sign of coords where g + rho*d rounds
     # to exactly 0 -- tolerate only those ULP-boundary cases
@@ -139,3 +143,39 @@ def test_fused_tally_acc_accumulates_to_merged_vote():
     merged = votes.vote_ar_int8(single_device_topology(), s_merged,
                                 w_merged, weight_bound=int(n_eff.max()))
     np.testing.assert_array_equal(np.asarray(vote), np.asarray(merged))
+
+
+@pytest.mark.parametrize("word_rows", [1, 3, 6, 70])
+def test_fused_kernels_any_row_count(word_rows):
+    """The flat-buffer kernels take any number of 4096-coordinate rows:
+    fewer than 8 (one whole-slab block) and a ragged last block past the
+    64-row block (70 = 64 + 6) -- row counts at which the old
+    power-of-two row block fell below the TPU's 8-row tile.  Every
+    kernel matches its oracle bitwise there, and the words unpack to
+    the plain sign vote."""
+    p, d = 2, 3
+    n = word_rows * 4096
+    key = jax.random.PRNGKey(10)
+    u = jax.random.normal(key, (p, d, n))
+    db = jax.random.normal(jax.random.fold_in(key, 1), (p, n))
+    words = ops.fused_pack_flat(u, db, 0.3, interpret=True)
+    u3 = u.reshape(p * d, -1, 128)
+    expect = ref.sign_pack_ref(u3, db.reshape(p, -1, 128), 0.3)
+    np.testing.assert_array_equal(np.asarray(words),
+                                  np.asarray(expect.reshape(p, d, -1)))
+    s = signs.sgn(u + 0.3 * db[:, None])
+    np.testing.assert_array_equal(np.asarray(ref.unpack_planes(words)),
+                                  np.asarray(s))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (p, n))
+    for mask in (None, jnp.asarray([[1, 0, 2], [0, 0, 0]])):
+        got = ops.fused_vote_update_words(words, v, mask, 0.01,
+                                          interpret=True)
+        m = None if mask is None else mask[:, :, None]
+        vote = signs.majority_vote(s, m, axis=1).astype(jnp.float32)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(v - 0.01 * vote))
+    w = jnp.asarray([[1, 2, 0], [3, 1, 1]])
+    tally = jnp.zeros((p, d, n), jnp.int8)
+    got = ops.fused_tally_acc_flat(u, db, 0.3, w, tally, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.tally_acc_ref(u, db, 0.3, w, tally)))
