@@ -13,13 +13,19 @@ each with ``--transport fused`` and again with ``--transport ag_packed``
 at ``state_layout="flat"``.  The two transports must print the same
 per-step losses digit for digit (the transports are bit-identical by
 construction).  Batch, sequence length, steps and K are cut; widths
-are not.  Each phase runs gemma3-1b (26 layers, d 1152, vocab 262144)
-when its step fits the chip's HBM, else xlstm-350m (24 blocks, d 1024),
-else gemma3-1b cut to 6 layers, printing each refusal with its bytes.
+and depths are not.  The merged phase runs gemma3-1b (26 layers,
+d 1152, vocab 262144); the stream phase runs xlstm-350m (24 blocks,
+d 1024), because gemma3-1b's streamed step does not fit one v5e's HBM
+(AOT compile for a described v5e: 17.8 GiB against 15.75 GiB).
+A step that does not fit the chip fails the run.
 
 ``--chips 4`` runs only the paper's hierarchy on one four-chip host:
 2 edges x 2 devices (pod x data), fused/flat against ag_packed/flat,
-on gemma3-1b at full width with its depth cut to 6 layers.
+on xlstm-350m at full width and depth (each chip holds one replica).
+gemma3-1b's ag_packed step does not fit a chip of that mesh: its round
+boundary holds the pod-axis all-reduces of the f32 master and of the
+embedding's anchor gradients at once (AOT compile for a described
+v5e:2x2 at 6, 12 and 18 layers, extrapolated: about 17 GiB a chip).
 It also requires a non-zero DC correction after the first round
 boundary (the cloud tier ran) and Pallas kernels in the compiled step.
 
@@ -46,13 +52,10 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(ROOT, "src")
-# full published widths, largest first: a phase runs the first that fits
-ARCHS = ("gemma3_1b", "xlstm_350m")
-# gemma3-1b's width with its depth cut to one 5:1 local:global attention
-# period: the last resort of a one-chip phase, and the four-chip phase's
-# model (an SPMD compile of the 26-layer step takes minutes of a
-# four-chip budget)
-CUT_DEPTH = 6
+# one model per phase, at its published width and depth
+MERGED_ARCH = "gemma3_1b"
+STREAM_ARCH = "xlstm_350m"
+HIER_ARCH = "xlstm_350m"
 
 
 class SmokeFailure(Exception):
@@ -73,7 +76,7 @@ def _run_cell(jax, name, cfg, topo, algo, run):
     from repro.kernels import ops as kops
     from repro.launch import train
 
-    route = (f"pallas kernels ({kops.fused_kernel_mode()})"
+    route = (f"fused kernel route {kops.fused_kernel_mode()}"
              if algo.transport == "fused" else "jnp per-leaf transport")
     _log(f"{name}: transport={algo.transport} layout={algo.state_layout} "
          f"K={algo.clients.count} mode={algo.clients.mode} "
@@ -118,40 +121,24 @@ def _compare(name, runs):
     _log(f"{name}: {ta} == {tb} on all {len(la)} losses")
 
 
-def _phase(jax, name, cfgs, topo, algo, run, check_state=None):
-    """Train with both transports on the first model config of ``cfgs``
-    whose step fits the chip's HBM; returns the config's name.
-
-    Only the compiler's or the runtime's RESOURCE_EXHAUSTED moves on to
-    the next (smaller) model, and the refusal is printed with its bytes;
-    any other error, and a loss mismatch, fails the phase."""
-    for i, cfg in enumerate(cfgs):
-        _log(f"{name}: {cfg.name}: {cfg.n_layers} layers, d {cfg.d_model}, "
-             f"vocab {cfg.vocab}")
-        runs = []
-        try:
-            for transport in ("fused", "ag_packed"):
-                a = dataclasses.replace(algo, transport=transport)
-                losses, state = _run_cell(jax, f"{name}/{transport}", cfg,
-                                          topo, a, run)
-                if check_state is not None:
-                    check_state(f"{name}/{transport}", state)
-                runs.append((transport, losses))
-                del state              # free the device state between runs
-                gc.collect()
-                jax.clear_caches()
-        except jax.errors.JaxRuntimeError as e:
-            if "RESOURCE_EXHAUSTED" not in str(e) or i + 1 == len(cfgs):
-                raise
-            _log(f"{name}: {cfg.name} does not fit the chip -- "
-                 f"{str(e).splitlines()[0][:400]}; running "
-                 f"{cfgs[i + 1].name} for this phase")
-        else:
-            _compare(name, runs)
-            return f"{cfg.name} ({cfg.n_layers} layers)"
+def _phase(jax, name, cfg, topo, algo, run, check_state=None):
+    """Train ``cfg`` with both transports and compare their losses;
+    returns the model's description.  Any error fails the phase."""
+    _log(f"{name}: {cfg.name}: {cfg.n_layers} layers, d {cfg.d_model}, "
+         f"vocab {cfg.vocab}")
+    runs = []
+    for transport in ("fused", "ag_packed"):
+        a = dataclasses.replace(algo, transport=transport)
+        losses, state = _run_cell(jax, f"{name}/{transport}", cfg, topo, a,
+                                  run)
+        if check_state is not None:
+            check_state(f"{name}/{transport}", state)
+        runs.append((transport, losses))
+        del state                      # free the device state between runs
         gc.collect()
         jax.clear_caches()
-    raise AssertionError("unreachable")
+    _compare(name, runs)
+    return f"{cfg.name} ({cfg.n_layers} layers)"
 
 
 def main(argv=None) -> int:
@@ -191,8 +178,6 @@ def main(argv=None) -> int:
     algo = hier.AlgoConfig(method="dc_hier_signsgd", mu=1e-3, rho=0.2,
                            t_e=2, state_layout="flat",
                            compute_dtype=jnp.bfloat16)
-    cut = dataclasses.replace(configs.get_config(ARCHS[0]),
-                              n_layers=CUT_DEPTH)
     t_start = time.perf_counter()
     ran = {}
     try:
@@ -211,22 +196,25 @@ def main(argv=None) -> int:
                     raise SmokeFailure(f"{name}: DC delta is zero: the "
                                        f"cloud tier did not run")
 
-            ran["hier2x2"] = _phase(jax, "hier2x2", [cut], topo, algo, run,
-                                    check_delta)
+            ran["hier2x2"] = _phase(jax, "hier2x2",
+                                    configs.get_config(HIER_ARCH), topo,
+                                    algo, run, check_delta)
         else:
             topo = single_device_topology()
-            cfgs = [configs.get_config(a) for a in ARCHS] + [cut]
             merged = train.RunCfg(steps=4, batch_per_device=1, seq_len=256,
                                   log_every=1, seed=args.seed)
-            ran["merged"] = _phase(jax, "merged", cfgs, topo, algo, merged)
+            ran["merged"] = _phase(jax, "merged",
+                                   configs.get_config(MERGED_ARCH), topo,
+                                   algo, merged)
             stream_algo = dataclasses.replace(
                 algo, clients=vclients.ClientConfig(
                     count=4, participation="bernoulli", rate=0.5,
                     mode="stream"))
             stream = dataclasses.replace(merged, batch_per_device=4,
                                          seq_len=128)
-            ran["stream"] = _phase(jax, "stream", cfgs, topo, stream_algo,
-                                   stream)
+            ran["stream"] = _phase(jax, "stream",
+                                   configs.get_config(STREAM_ARCH), topo,
+                                   stream_algo, stream)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

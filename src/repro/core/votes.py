@@ -293,7 +293,7 @@ def tally_accumulate_words(words: jax.Array, weights: jax.Array,
 
     words: [P, D, W] uint32 (the client's 1-bit uplink payload);
     weights: [P, D] integer vote weights; tally: [P, D, W*32] signed
-    tally.  Per coordinate ``tally += w * (2*bit - 1)`` -- the same
+    tally, or any shape of [P, D, ...] in the same coordinate order.  Per coordinate ``tally += w * (2*bit - 1)`` -- the same
     weighted popcount as the merged transports, deferred: summing these
     contributions over clients gives ``t = 2*pos - n_eff``.
     """
@@ -672,9 +672,9 @@ def fused_sign_tally_accumulate(topo: Topology, layout: flatbuf.FlatLayout,
     delta_buf: optional DC correction ([P, *leaf] tree or [P, n_pad]
     buffer), fused pre-sign exactly like :func:`fused_sign_vote`;
     weights: [P, D] integer vote weights of this client this round;
-    tally: [P, D, n_pad] or lane-row [P, D, n_pad/128, 128] signed
-    tally (``tally_dtype(weight_bound)``).  Returns the updated tally
-    in the same shape.  No collective runs here -- the data
+    tally: lane-row [P, D, n_pad/128, 128] signed tally
+    (``tally_dtype(weight_bound)``).  Returns the updated tally in the
+    same shape.  No collective runs here -- the data
     exchange of the streamed sweep happens once per local step in
     :func:`fused_tally_finish`.
 
@@ -710,7 +710,7 @@ def _tally_acc_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
 
     One shard_map program with ZERO collectives: rank (p, d, m) packs
     its own model-axis bucket of this client's directions and folds the
-    weighted signs into its local [1, 1, bucket_pad] tally block.
+    weighted signs into its local [1, 1, bucket_pad/128, 128] tally block.
     """
     bucket = layout.bucket()
     u_dev = flatbuf.pad_tree(layout, u_dev, 2)
@@ -762,8 +762,7 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
     per-device tallies over ``data`` ONCE per local step, defer-threshold
     into the vote, and optionally apply ``v <- v - mu*vote``.
 
-    tally: [P, D, n_pad] or lane-row [P, D, n_pad/128, 128]
-    accumulated signed tallies (all K clients folded in); n_eff: [P]
+    tally: lane-row [P, D, n_pad/128, 128] accumulated signed tallies (all K clients folded in); n_eff: [P]
     int32 participating weight sum of the round.
     With ``v_buf`` (flat state) returns the updated [P, n_pad] buffer;
     without it returns the vote as a [P, *leaf] int8 pytree -- mirroring

@@ -272,15 +272,15 @@ def fused_tally_acc_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     client (physical device axis, NOT the merged D*K); d_buf: [P, n_pad]
     correction or None (same fold rules as ``fused_pack_flat``);
     weights: [P, D] integer vote weights of this client; tally:
-    [P, D, n_pad] or lane-row [P, D, n_pad/128, 128] signed tally
-    (int8/int16/int32 per ``core.votes.tally_dtype``), returned in the
-    same shape.  ONE ``tally_acc`` read-modify-write
+    lane-row [P, D, n_pad/128, 128] signed tally (int8/int16/int32 per
+    ``core.votes.tally_dtype``), returned in the same shape.  ONE ``tally_acc`` read-modify-write
     sweep over all P*D voter slabs -- the client's sign plane never
     reaches HBM, and the delta block is re-read per voter through its
     BlockSpec exactly like ``fused_pack_flat``.
     """
     p, d, n = u_buf.shape
-    assert tally.size == u_buf.size, (tally.shape, u_buf.shape)
+    assert tally.shape == (p, d, n // LANES, LANES), (tally.shape,
+                                                       u_buf.shape)
     g3, d3 = _slabs(u_buf, d_buf, rho)
     out = _ta.tally_acc(g3, d3, weights.reshape(p * d),
                         tally.reshape(g3.shape), rho=rho,

@@ -20,7 +20,13 @@ program):
    the layout must stay ``shards == 2`` with ``shard_dim`` set (NO
    per-bucket copy), trajectories must stay bitwise vs the tree-state
    reference, and the optimized HLO must still carry zero model-axis
-   all-gather bytes.
+   all-gather bytes;
+5. the paper's 2 edge x 2 device layout with NO model axis (2x2x1):
+   the unsharded flat layout still runs the per-rank shard_map program
+   when the kernels run (interpret mode on CPU), and fused/flat is
+   BITWISE ``ag_packed``/flat merged at K = 1 and at K = 2 with
+   Bernoulli participation, and streamed at K = 2 with Bernoulli
+   participation.
 
 Run directly (forces 8 host devices before importing jax):
     PYTHONPATH=src python tests/helpers/sharded_fused_check.py
@@ -123,4 +129,49 @@ ag_u = hlo_analysis.collective_bytes(stats_u, op="all-gather")
 assert 0 < ag_u <= 4 * lay_u.n_words, (ag_u, 4 * lay_u.n_words)
 print(f"uneven HLO: zero model-axis all-gather bytes; uplink "
       f"{ag_u:.0f} B <= packed payload bound {4 * lay_u.n_words} B")
+
+# ---- 5. 2 edges x 2 devices, no model axis: per-rank kernel route ----
+import collections  # noqa: E402
+import dataclasses  # noqa: E402
+from repro.core import votes  # noqa: E402
+
+mesh4 = Mesh(np.array(jax.devices()[:Pn * Dn]).reshape(Pn, Dn, 1),
+             ("pod", "data", "model"))
+topo4 = Topology(mesh=mesh4, pod_axis="pod")
+small4 = H.make_problem(Pn, Dn, rounds=1, t_e=2)
+# count traces of the per-rank programs: both must engage with no model
+# axis (merged -> fused chain, stream -> tally accumulation)
+traced = collections.Counter()
+
+
+def _counting(name):
+    fn = getattr(votes, name)
+
+    def wrapped(topo, layout, *args, **kw):
+        assert layout.shards == 1, layout
+        traced[name] += 1
+        return fn(topo, layout, *args, **kw)
+    setattr(votes, name, wrapped)
+
+
+_counting("_fused_shard_map")
+_counting("_tally_acc_shard_map")
+os.environ["REPRO_FUSED_PALLAS"] = "interpret"
+sampled = H.client_cfg(Pn, Dn, 2, "sampled")
+for tag, kw, program in (
+        ("K=1", {}, "_fused_shard_map"),
+        ("K=2 bernoulli", {"clients": sampled}, "_fused_shard_map"),
+        ("K=2 bernoulli stream",
+         {"clients": dataclasses.replace(sampled, mode="stream")},
+         "_tally_acc_shard_map")):
+    ref4, _ = H.run_hier(topo4, small4, "dc_hier_signsgd", "ag_packed",
+                         "flat", **kw)
+    before = traced[program]
+    got4, _ = H.run_hier(topo4, small4, "dc_hier_signsgd", "fused", "flat",
+                         **kw)
+    assert traced[program] > before, (tag, program, traced)
+    H.assert_trees_equal(ref4, got4, f"2x2x1/fused/flat/{tag}")
+    print(f"2x2x1 per-rank kernel route ({program}) bitwise parity OK "
+          f"({tag})")
+del os.environ["REPRO_FUSED_PALLAS"]
 print("sharded fused check OK")

@@ -114,12 +114,15 @@ def test_fused_tally_acc_matches_ref(n, rho, acc_dtype):
     db = (jax.random.normal(jax.random.fold_in(key, 1), (p, n))
           if rho else None)
     w = jax.random.randint(jax.random.fold_in(key, 2), (p, d), 0, 5)
-    tally = jax.random.randint(jax.random.fold_in(key, 3), (p, d, n),
-                               -20, 20).astype(acc_dtype)
+    tally = jax.random.randint(jax.random.fold_in(key, 3),
+                               (p, d, n // 128, 128), -20,
+                               20).astype(acc_dtype)
     got = ops.fused_tally_acc_flat(u, db, rho, w, tally, interpret=True)
-    expect = ref.tally_acc_ref(u, db, rho, w, tally)
+    expect = ref.tally_acc_ref(u, db, rho, w, tally.reshape(p, d, n))
     assert got.dtype == acc_dtype
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
+    assert got.shape == tally.shape
+    np.testing.assert_array_equal(np.asarray(got).reshape(p, d, n),
+                                  np.asarray(expect))
 
 
 def test_fused_tally_acc_accumulates_to_merged_vote():
@@ -130,13 +133,13 @@ def test_fused_tally_acc_accumulates_to_merged_vote():
     key = jax.random.PRNGKey(9)
     us = jax.random.normal(key, (k, p, d, n))
     ws = jax.random.randint(jax.random.fold_in(key, 1), (k, p, d), 0, 3)
-    tally = jnp.zeros((p, d, n), jnp.int8)
+    tally = jnp.zeros((p, d, n // 128, 128), jnp.int8)
     for c in range(k):
         tally = ops.fused_tally_acc_flat(us[c], None, 0.0, ws[c], tally,
                                          interpret=True)
     n_eff = jnp.sum(ws.astype(jnp.int32), axis=(0, 2))
-    vote = votes.tally_vote(jnp.sum(tally.astype(jnp.int32), axis=1),
-                            n_eff)
+    vote = votes.tally_vote(
+        jnp.sum(tally.astype(jnp.int32), axis=1).reshape(p, n), n_eff)
     s_merged = signs.sgn(us.transpose(1, 0, 2, 3).reshape(p, k * d, n))
     w_merged = ws.transpose(1, 0, 2).reshape(p, k * d)
     from repro.core.topology import single_device_topology
@@ -175,7 +178,9 @@ def test_fused_kernels_any_row_count(word_rows):
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(v - 0.01 * vote))
     w = jnp.asarray([[1, 2, 0], [3, 1, 1]])
-    tally = jnp.zeros((p, d, n), jnp.int8)
+    tally = jnp.zeros((p, d, n // 128, 128), jnp.int8)
     got = ops.fused_tally_acc_flat(u, db, 0.3, w, tally, interpret=True)
     np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(ref.tally_acc_ref(u, db, 0.3, w, tally)))
+        np.asarray(got).reshape(p, d, n),
+        np.asarray(ref.tally_acc_ref(u, db, 0.3, w,
+                                     tally.reshape(p, d, n))))

@@ -1,5 +1,11 @@
 """Integration: the end-to-end driver trains, checkpoints, resumes, and
 survives injected faults (device loss -> quorum vote; elastic reweight)."""
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import pytest
 
@@ -136,3 +142,19 @@ def test_cli_rejects_bad_client_carve():
     assert "does not divide into" in r.stderr
     assert "--clients_per_device" in r.stderr
     assert "Traceback" not in r.stderr
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_refuses_without_tpu(tmp_path, where):
+    """``chip_smoke.py`` has no CPU fallback: with only CPU devices, or
+    run outside a checkout of the repository, it exits non-zero and
+    prints no result line."""
+    script = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    if where == "alone":
+        script = pathlib.Path(shutil.copy(script, tmp_path))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, str(script)], cwd=script.parent,
+                       env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0, (r.stdout, r.stderr)
+    assert '"ok"' not in r.stdout, r.stdout
