@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Chip smoke test: DC-HierSignSGD training on TPU through ``run_training``.
 
-Default (one chip): a model at its full published width (random
-weights from ``--seed``) trains 2 rounds at T_E = 2 -- so the
-round-boundary step runs twice -- in two phases:
+Default (one chip): the jnp wire format is checked against numpy
+(``pack_signs`` at gemma3-1b leaf shapes); then a model at its full
+published width (random weights from ``--seed``) trains 2 rounds at
+T_E = 2 -- so the round-boundary step runs twice -- in two phases:
 
   merged  K = 1 voter per device (kernels: sign_pack + vote_update);
   stream  K = 4 streamed virtual clients, Bernoulli participation 0.5
@@ -21,11 +22,7 @@ A step that does not fit the chip fails the run.
 
 ``--chips 4`` runs only the paper's hierarchy on one four-chip host:
 2 edges x 2 devices (pod x data), fused/flat against ag_packed/flat,
-on xlstm-350m at full width and depth (each chip holds one replica).
-gemma3-1b's ag_packed step does not fit a chip of that mesh: its round
-boundary holds the pod-axis all-reduces of the f32 master and of the
-embedding's anchor gradients at once (AOT compile for a described
-v5e:2x2 at 6, 12 and 18 layers, extrapolated: about 17 GiB a chip).
+on gemma3-1b at full width and depth (each chip holds one replica).
 It also requires a non-zero DC correction after the first round
 boundary (the cloud tier ran) and Pallas kernels in the compiled step.
 
@@ -55,7 +52,8 @@ SRC = os.path.join(ROOT, "src")
 # one model per phase, at its published width and depth
 MERGED_ARCH = "gemma3_1b"
 STREAM_ARCH = "xlstm_350m"
-HIER_ARCH = "xlstm_350m"
+HIER_ARCH = "gemma3_1b"
+WIRE_SHAPES = ((1152,), (22, 1152), (4, 288), (1152, 6912))
 
 
 class SmokeFailure(Exception):
@@ -103,7 +101,7 @@ def _run_cell(jax, name, cfg, topo, algo, run):
     _log(f"{name}: step seconds {[round(s, 4) for s in secs]} (first "
          f"includes tracing; steady median "
          f"{statistics.median(secs[1:]):.4f}s); peak_bytes_in_use "
-         f"{_peak_bytes(jax)}")
+         f"{_peak_bytes(jax)} (process peak so far)")
     if algo.transport == "fused" and info.get("custom_calls", 0) <= 0:
         raise SmokeFailure(f"{name}: no tpu_custom_call in the fused step")
     if not all(x == x and abs(x) != float("inf") for x in losses):
@@ -139,6 +137,28 @@ def _phase(jax, name, cfg, topo, algo, run, check_state=None):
         jax.clear_caches()
     _compare(name, runs)
     return f"{cfg.name} ({cfg.n_layers} layers)"
+
+
+def _wire_check(jax, seed):
+    """The jnp transports' wire format on the chip: ``pack_signs`` of
+    the signs of random floats equals a numpy pack, word for word, at
+    gemma3-1b leaf shapes (norm scales, q/k norms, an MLP matrix).  The
+    TPU compiler once set bits 16-22 wrong for the small ones only, so
+    both transports' losses cannot vouch for it alone."""
+    import numpy as np
+    from repro.core import signs
+
+    rng = np.random.default_rng(seed)
+    pack = jax.jit(lambda u: signs.pack_signs(signs.sgn(u)))
+    for shape in WIRE_SHAPES:
+        u = rng.standard_normal(shape, dtype=np.float32)
+        bits = (u >= 0).astype(np.uint64).reshape(shape[:-1] + (-1, 32))
+        want = (bits << np.arange(32, dtype=np.uint64)).sum(-1)
+        bad = int(np.sum(np.asarray(pack(u)) != want))
+        if bad:
+            raise SmokeFailure(f"wire: pack_signs wrong in {bad} of "
+                               f"{want.size} words of a {shape} leaf")
+    _log(f"wire: pack_signs equals the numpy pack at {WIRE_SHAPES}")
 
 
 def main(argv=None) -> int:
@@ -200,6 +220,7 @@ def main(argv=None) -> int:
                                     configs.get_config(HIER_ARCH), topo,
                                     algo, run, check_delta)
         else:
+            _wire_check(jax, args.seed)
             topo = single_device_topology()
             merged = train.RunCfg(steps=4, batch_per_device=1, seq_len=256,
                                   log_every=1, seed=args.seed)

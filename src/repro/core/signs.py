@@ -61,14 +61,14 @@ def pack_signs(signs: jax.Array) -> jax.Array:
     Positive sign -> bit 1.  Padding bits are 1 (+1 sign).
     """
     flat = _pad_to_multiple(signs, PACK_WIDTH)
-    bits = (flat > 0).reshape(flat.shape[:-1] + (-1, PACK_WIDTH))
-    # an OR of 32 shifted bit slices (disjoint bits: equal to their
-    # sum) fuses into one elementwise pass; a sum over a shifted
-    # [..., 32] uint32 tensor makes XLA materialize 4 bytes per sign
-    word = bits[..., 0].astype(jnp.uint32)
-    for j in range(1, PACK_WIDTH):
-        word = word | (bits[..., j].astype(jnp.uint32) << jnp.uint32(j))
-    return word
+    bits = (flat > 0).astype(jnp.uint32)
+    bits = bits.reshape(bits.shape[:-1] + (-1, PACK_WIDTH))
+    shifts = jnp.arange(PACK_WIDTH, dtype=jnp.uint32)
+    # a reduction over the 32 shifted bits (disjoint: sum == OR).  Not
+    # an OR chain of 32 strided slices: the TPU compiler (jaxlib 0.9.0)
+    # gets bits 16-22 of every word wrong for leaves of a few thousand
+    # coordinates (e.g. a [1152] norm scale)
+    return jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
 
 
 def unpack_signs(words: jax.Array, n: int) -> jax.Array:
