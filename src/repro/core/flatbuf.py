@@ -107,7 +107,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core import signs
+from repro.core import signs, stages
 
 PyTree = Any
 
@@ -399,6 +399,7 @@ def _pad_shard_tail(slot: LeafSlot, leaf: jax.Array, batch_dims: int):
     return jnp.pad(leaf, pads)
 
 
+@stages.in_stage("relayout")
 def pad_tree(layout: FlatLayout, tree: PyTree,
              batch_dims: int = 0) -> PyTree:
     """Logical tree -> the layout's padded-shard shapes (zero tails).
@@ -414,6 +415,7 @@ def pad_tree(layout: FlatLayout, tree: PyTree,
          for s, leaf in zip(layout.slots, leaves)])
 
 
+@stages.in_stage("relayout")
 def unpad_tree(layout: FlatLayout, tree: PyTree,
                batch_dims: int = 0) -> PyTree:
     """Inverse of :func:`pad_tree`: slice each leaf back to its logical
@@ -429,6 +431,7 @@ def unpad_tree(layout: FlatLayout, tree: PyTree,
     return layout.treedef.unflatten(out)
 
 
+@stages.in_stage("relayout")
 def bucket_trees(layout: FlatLayout, tree: PyTree,
                  batch_dims: int = 0) -> list[PyTree]:
     """Per-bucket local trees of a sharded layout (static slices).
@@ -465,6 +468,7 @@ def _flat_leaf(slot: LeafSlot, leaf: jax.Array, batch_dims: int):
     return flat
 
 
+@stages.in_stage("relayout")
 def flatten_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
                  dtype: Any = None) -> jax.Array:
     """tree -> ``[*batch, n_pad]`` buffer in the (promoted) buffer dtype.
@@ -499,6 +503,7 @@ def flatten_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
     return buf.reshape(batch + (layout.n_pad,))
 
 
+@stages.in_stage("relayout")
 def unflatten_tree(layout: FlatLayout, buf: jax.Array, batch_dims: int = 0,
                    cast: bool = True) -> PyTree:
     """``[*batch, n_pad]`` buffer -> pytree of slice views.
@@ -567,10 +572,11 @@ def pack_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
         uts = bucket_trees(layout, tree, batch_dims)
         dts = (bucket_trees(layout, delta, delta_batch_dims)
                if delta is not None else [None] * layout.shards)
-        return jnp.concatenate(
-            [pack_tree(bucket, ut, batch_dims=batch_dims, delta=dt,
-                       rho=rho, delta_batch_dims=delta_batch_dims)
-             for ut, dt in zip(uts, dts)], axis=-1)
+        words = [pack_tree(bucket, ut, batch_dims=batch_dims, delta=dt,
+                           rho=rho, delta_batch_dims=delta_batch_dims)
+                 for ut, dt in zip(uts, dts)]
+        with stages.scope("sign_pack"):
+            return jnp.concatenate(words, axis=-1)
     leaves = layout.treedef.flatten_up_to(tree)
     dl_leaves = (layout.treedef.flatten_up_to(delta)
                  if delta is not None else [None] * len(leaves))
@@ -585,18 +591,21 @@ def pack_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
                                   0xFFFFFFFF, jnp.uint32))
             continue
         if dl is not None and rho:
-            dlf = dl.reshape(dl.shape[:delta_batch_dims] + (slot.size,))
-            dlf = _with_mid_axes(dlf, delta_batch_dims, batch_dims)
-            u = u + rho * dlf.astype(u.dtype)
-        w = signs.pack_signs(signs.sgn(u))                # pads to +1 bits
-        if w.shape[-1] != slot.words:
-            w = jnp.pad(w, [(0, 0)] * batch_dims
-                        + [(0, slot.words - w.shape[-1])],
-                        constant_values=jnp.uint32(0xFFFFFFFF))
+            with stages.scope("correction"):
+                dlf = dl.reshape(dl.shape[:delta_batch_dims] + (slot.size,))
+                dlf = _with_mid_axes(dlf, delta_batch_dims, batch_dims)
+                u = u + rho * dlf.astype(u.dtype)
+        with stages.scope("sign_pack"):
+            w = signs.pack_signs(signs.sgn(u))            # pads to +1 bits
+            if w.shape[-1] != slot.words:
+                w = jnp.pad(w, [(0, 0)] * batch_dims
+                            + [(0, slot.words - w.shape[-1])],
+                            constant_values=jnp.uint32(0xFFFFFFFF))
         parts.append(w)
-    words = jnp.concatenate(parts, axis=-1)
-    tail = layout.n_words - words.shape[-1]
-    if tail:
-        words = jnp.pad(words, [(0, 0)] * batch_dims + [(0, tail)],
-                        constant_values=jnp.uint32(0xFFFFFFFF))
+    with stages.scope("sign_pack"):
+        words = jnp.concatenate(parts, axis=-1)
+        tail = layout.n_words - words.shape[-1]
+        if tail:
+            words = jnp.pad(words, [(0, 0)] * batch_dims + [(0, tail)],
+                            constant_values=jnp.uint32(0xFFFFFFFF))
     return words

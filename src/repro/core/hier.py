@@ -87,7 +87,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import clients as vclients
-from repro.core import device_axis, flatbuf, schedule, shardflat, signs, votes
+from repro.core import (device_axis, flatbuf, schedule, shardflat, signs,
+                        stages, votes)
 from repro.core.device_axis import LiftCfg
 from repro.core.topology import Topology
 
@@ -281,6 +282,12 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
           'always'/'never' -- statically include/skip the prologue (used by
           the dry-run so cost_analysis sees straight-line programs: a
           global round costs (T_E-1) x never + 1 x always).
+
+    metrics: ``loss``, ``loss_per_pod`` [P], ``mu``, and two int32
+    counts of the voter passes: ``voter_passes`` (forward and backward
+    passes the step computed, P*D*K) and ``votes_cast`` (those whose
+    vote weight is non-zero).  The step's work is scoped by stage
+    (``core.stages``).
     """
     t_e = algo.t_e
     fsdp = bundle.param_mode == "fsdp"
@@ -337,6 +344,7 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
     vmap2 = lambda f: jax.vmap(jax.vmap(f))
 
     # ---------------- gradient machinery -------------------------------
+    @stages.in_stage("grads")
     def per_device_grads(params, batch, rngs, devices=None):
         """Replicated regime: explicit [P, D, ...] per-(virtual-)device
         grads (the voter axis is the merged D*K extent when virtual
@@ -735,6 +743,7 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             lambda x, s: topo.constrain(x, topo.pod_spec(*s)),
             tree, bundle.master_specs)
 
+    @stages.in_stage("relayout")
     def master_views(fs):
         """Flat state -> leaf views, re-constrained to the per-leaf master
         layout so the loss compiles to the SAME partitioned compute as the
@@ -756,6 +765,7 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             lambda x: topo.constrain(x, spec(*([None] * (x.ndim - lead)))),
             tree)
 
+    @stages.in_stage("relayout")
     def flatten_buf(layout, tree, batch_dims, dtype=None):
         """tree -> flat buffer without unsharding TP leaves: per-bucket
         shard_map writes for sharded layouts, the ``gather_leafdims``
@@ -813,16 +823,18 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             fold_dc = (algo.transport == "fused" and algo.is_dc
                        and not algo.error_feedback)
             if algo.is_dc and not fold_dc:
-                d_dev = _bcast_pd(topo, delta, bundle.compute_specs, None,
-                                  devices=d_virtual)
-                u_dev = jax.tree.map(
-                    lambda u, dl: u + algo.rho * dl.astype(u.dtype),
-                    u_dev, d_dev)
+                with stages.scope("correction"):
+                    d_dev = _bcast_pd(topo, delta, bundle.compute_specs,
+                                      None, devices=d_virtual)
+                    u_dev = jax.tree.map(
+                        lambda u, dl: u + algo.rho * dl.astype(u.dtype),
+                        u_dev, d_dev)
             if algo.has_client_correction:
-                q_dev = client_correction_dev(corr_cl, corr_edge)
-                u_dev = jax.tree.map(
-                    lambda u, ql: u + algo.rho * ql.astype(u.dtype),
-                    u_dev, q_dev)
+                with stages.scope("correction"):
+                    q_dev = client_correction_dev(corr_cl, corr_edge)
+                    u_dev = jax.tree.map(
+                        lambda u, ql: u + algo.rho * ql.astype(u.dtype),
+                        u_dev, q_dev)
             if algo.transport == "fused" and not algo.error_feedback:
                 direction = votes.fused_sign_vote(
                     topo, u_dev, delta if fold_dc else None,
@@ -856,8 +868,9 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             # flatten the int8 vote (exact in any float dtype) and cast
             # inside the update: no f32 copy of the direction
             dir_buf = flatten_buf(layout, vote_tree, 1, jnp.int8)
-            return params.replace(
-                params.buf - mu * dir_buf.astype(params.buf.dtype))
+            with stages.scope("vote_update"):
+                return params.replace(
+                    params.buf - mu * dir_buf.astype(params.buf.dtype))
 
         if algo.method == "hier_sgd":
             g_buf = flatten_buf(layout, g_dev, 2, jnp.float32)
@@ -898,18 +911,20 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
         fold_dc = (algo.transport == "fused" and algo.is_dc
                    and not algo.error_feedback)
         if algo.is_dc and not fold_dc:
-            d_dev = _bcast_pd(topo, shardflat.tree_views(topo, delta,
-                                                         cast=False),
-                              bundle.compute_specs, None,
-                              devices=d_virtual)
-            u_dev = jax.tree.map(
-                lambda u, dl: u + algo.rho * dl.astype(u.dtype),
-                u_dev, d_dev)
+            with stages.scope("correction"):
+                d_dev = _bcast_pd(topo, shardflat.tree_views(topo, delta,
+                                                             cast=False),
+                                  bundle.compute_specs, None,
+                                  devices=d_virtual)
+                u_dev = jax.tree.map(
+                    lambda u, dl: u + algo.rho * dl.astype(u.dtype),
+                    u_dev, d_dev)
         if algo.has_client_correction:
-            q_dev = client_correction_dev(corr_cl, corr_edge)
-            u_dev = jax.tree.map(
-                lambda u, ql: u + algo.rho * ql.astype(u.dtype),
-                u_dev, q_dev)
+            with stages.scope("correction"):
+                q_dev = client_correction_dev(corr_cl, corr_edge)
+                u_dev = jax.tree.map(
+                    lambda u, ql: u + algo.rho * ql.astype(u.dtype),
+                    u_dev, q_dev)
         if algo.transport == "fused" and not algo.error_feedback:
             # the whole-model v <- v - mu*vote is ONE vote_update
             # read-modify-write over the packed-word buffer (mu folded
@@ -959,20 +974,23 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
         # only); clients re-read it each iteration
         delta_tree = None
         if algo.is_dc and not fold_dc and algo.is_sign:
-            dt = (shardflat.tree_views(topo, delta, cast=False)
-                  if flat else delta)
-            delta_tree = _bcast_pd(topo, dt, bundle.compute_specs, None,
-                                   devices=d)
+            with stages.scope("correction"):
+                dt = (shardflat.tree_views(topo, delta, cast=False)
+                      if flat else delta)
+                delta_tree = _bcast_pd(topo, dt, bundle.compute_specs, None,
+                                       devices=d)
         # ... and so does the scaffold/mtgc edge-level term; the
         # per-client term (corr3) is sliced per client inside the loop
         ce_tree = corr3 = None
         if algo.has_client_correction:
-            ce = (shardflat.tree_views(topo, corr_edge, cast=False)
-                  if flat else corr_edge)
-            ce_tree = _bcast_pd(topo, ce, bundle.compute_specs, None,
-                                devices=d)
+            with stages.scope("correction"):
+                ce = (shardflat.tree_views(topo, corr_edge, cast=False)
+                      if flat else corr_edge)
+                ce_tree = _bcast_pd(topo, ce, bundle.compute_specs, None,
+                                    devices=d)
 
         # per-voter state views sliced per client inside the loop
+        @stages.in_stage("relayout")
         def views3(fs_or_tree):
             t = (shardflat.tree_views(topo, fs_or_tree, cast=False)
                  if flat else fs_or_tree)
@@ -1087,18 +1105,22 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                     u_c = jax.tree.map(
                         lambda u, e: u.astype(jnp.float32) + e, u_c, e_c)
             if delta_tree is not None:
-                u_c = jax.tree.map(
-                    lambda u, dl: u + algo.rho * dl.astype(u.dtype),
-                    u_c, delta_tree)
+                with stages.scope("correction"):
+                    u_c = jax.tree.map(
+                        lambda u, dl: u + algo.rho * dl.astype(u.dtype),
+                        u_c, delta_tree)
             if ce_tree is not None:
-                cl_c = take_c(corr3, c_idx)
-                if algo.is_scaffold:
-                    q_c = jax.tree.map(lambda e, cv: e - cv, ce_tree, cl_c)
-                else:
-                    q_c = jax.tree.map(lambda cv, e: cv + e, cl_c, ce_tree)
-                u_c = jax.tree.map(
-                    lambda u, ql: u + algo.rho * ql.astype(u.dtype),
-                    u_c, q_c)
+                with stages.scope("correction"):
+                    cl_c = take_c(corr3, c_idx)
+                    if algo.is_scaffold:
+                        q_c = jax.tree.map(lambda e, cv: e - cv, ce_tree,
+                                           cl_c)
+                    else:
+                        q_c = jax.tree.map(lambda cv, e: cv + e, cl_c,
+                                           ce_tree)
+                    u_c = jax.tree.map(
+                        lambda u, ql: u + algo.rho * ql.astype(u.dtype),
+                        u_c, q_c)
             if fuse:
                 tally_f = votes.fused_sign_tally_accumulate(
                     topo, vlayout, u_c,
@@ -1111,9 +1133,10 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                     ef_c = put_c(ef_c,
                                  ef_residual(u_c, s_c, part=(w_c > 0)),
                                  c_idx)
-                tally_t = jax.tree.map(
-                    lambda t, s: votes.tally_add_signs(t, s, w_c),
-                    tally_t, s_c)
+                with stages.scope("tally_acc"):
+                    tally_t = jax.tree.map(
+                        lambda t, s: votes.tally_add_signs(t, s, w_c),
+                        tally_t, s_c)
             return (tally_f, tally_t, acc_c, ef_c, mom_c, loss_c)
 
         tally_flat, tally_tree, acc, ef3, mom3, losses3 = jax.lax.fori_loop(
@@ -1161,9 +1184,10 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                     lambda v, s: v - mu * s.astype(v.dtype), params,
                     direction)
         else:
-            direction = jax.tree.map(
-                lambda t, cs: votes.tally_vote_dev(topo, t, n_eff, cs),
-                tally_tree, bundle.compute_specs)
+            with stages.scope("tally_finish"):
+                direction = jax.tree.map(
+                    lambda t, cs: votes.tally_vote_dev(topo, t, n_eff, cs),
+                    tally_tree, bundle.compute_specs)
             if flat:
                 dir_buf = flatten_buf(layout, direction, 1,
                                       params.buf.dtype)
@@ -1230,6 +1254,11 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             shares = dev_weights
             carve = lambda b: b
             corr_part = None          # legacy path updates unconditionally
+        # useful work of the voter passes: the step runs a forward and
+        # backward pass for every voter, absent ones included, and only
+        # a pass with a non-zero vote weight casts a vote
+        voter_passes = jnp.int32(topo.pods * d_virtual)
+        votes_cast = jnp.sum(vote_w > 0, dtype=jnp.int32)
         train_batch = carve(batch["train"])
         anchor_batch = carve(batch.get("anchor", batch["train"]))
         agg_shares = shares3 if stream else shares
@@ -1243,19 +1272,23 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
         # at the committed (one-round-stale) model.
         def prologue(op):
             params, agg_next, delta, delta_next, corr_cl, corr_edge = op
-            issued = constrain_master(pod_avg(params, edge_weights))
-            params, agg_next = cloud_sched.commit(issued, agg_next)
+            with stages.scope("cloud_mean"):
+                issued = constrain_master(pod_avg(params, edge_weights))
+                params, agg_next = cloud_sched.commit(issued, agg_next)
             if algo.is_dc:
-                fresh = compute_delta(params, delta, anchor_batch, rngs_a,
-                                      edge_weights, agg_shares, maskf)
+                with stages.scope("anchor"):
+                    fresh = compute_delta(params, delta, anchor_batch,
+                                          rngs_a, edge_weights, agg_shares,
+                                          maskf)
                 if algo.anchor_staleness == 1:
                     delta, delta_next = delta_next, fresh
                 else:
                     delta = fresh
             if algo.has_client_correction:
-                corr_cl, corr_edge = compute_corrections(
-                    params, corr_cl, corr_edge, anchor_batch, rngs_a,
-                    edge_weights, agg_shares, corr_part, rnd_index)
+                with stages.scope("anchor"):
+                    corr_cl, corr_edge = compute_corrections(
+                        params, corr_cl, corr_edge, anchor_batch, rngs_a,
+                        edge_weights, agg_shares, corr_part, rnd_index)
             return params, agg_next, delta, delta_next, corr_cl, corr_edge
 
         def no_op(op):
@@ -1304,6 +1337,8 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
             "loss": jnp.mean(losses.astype(jnp.float32)),
             "loss_per_pod": jnp.mean(losses.astype(jnp.float32), axis=1),
             "mu": mu,
+            "voter_passes": voter_passes,
+            "votes_cast": votes_cast,
         }
         return new_state, metrics
 

@@ -41,7 +41,7 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import flatbuf
+from repro.core import flatbuf, stages
 from repro.core.topology import Topology
 
 PyTree = Any
@@ -84,6 +84,7 @@ def _smap(topo: Topology, fn, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
+@stages.in_stage("relayout")
 def flatten(topo: Topology, layout: flatbuf.FlatLayout, tree: PyTree,
             batch_dims: int = 1, dtype: Any = None) -> jax.Array:
     """Sharded ``flatten_tree``: each rank writes only its own bucket.
@@ -106,6 +107,7 @@ def flatten(topo: Topology, layout: flatbuf.FlatLayout, tree: PyTree,
                  buf_spec(topo, layout, batch_dims))(tree)
 
 
+@stages.in_stage("relayout")
 def tree_views(topo: Topology, fs: flatbuf.FlatState,
                cast: bool = True) -> PyTree:
     """Sharded ``FlatState.tree()``: leaf views without model gathers.

@@ -90,7 +90,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import flatbuf, shardflat, signs
+from repro.core import flatbuf, shardflat, signs, stages
 from repro.core.topology import Topology
 from repro.kernels import ops as kops
 
@@ -338,25 +338,28 @@ def _fused_kernel_bufs(layout, u_dev, delta_tree, delta_buf, rho):
         if delta_tree is None:
             delta_tree = flatbuf.unflatten_tree(layout, delta_buf,
                                                 batch_dims=1, cast=False)
-        u_dev = jax.tree.map(
-            lambda u, dl: u + rho * dl[:, None].astype(u.dtype),
-            u_dev, delta_tree)
+        with stages.scope("correction"):
+            u_dev = jax.tree.map(
+                lambda u, dl: u + rho * dl[:, None].astype(u.dtype),
+                u_dev, delta_tree)
     # flatten in the promoted dtype over the u leaves: widening casts
     # never move a value across zero, so the signs stay bit-identical to
     # pack_tree's per-leaf-dtype arithmetic
     dt = leaves[0].dtype
     for leaf in leaves[1:]:
         dt = jnp.promote_types(dt, leaf.dtype)
-    u_buf = flatbuf.flatten_tree(layout, u_dev, batch_dims=2, dtype=dt)
-    if not jnp.issubdtype(u_buf.dtype, jnp.floating):
-        # EF hands pre-signed int8 trees in; the kernels take float
-        # blocks (int8 VMEM tiling differs), and +-1 casts exactly.
-        u_buf = u_buf.astype(jnp.float32)
-    d_buf = None
-    if fold_in_kernel:
-        d_buf = (delta_buf.astype(u_buf.dtype) if delta_buf is not None
-                 else flatbuf.flatten_tree(layout, delta_tree, batch_dims=1,
-                                           dtype=u_buf.dtype))
+    with stages.scope("relayout"):
+        u_buf = flatbuf.flatten_tree(layout, u_dev, batch_dims=2, dtype=dt)
+        if not jnp.issubdtype(u_buf.dtype, jnp.floating):
+            # EF hands pre-signed int8 trees in; the kernels take float
+            # blocks (int8 VMEM tiling differs), and +-1 casts exactly.
+            u_buf = u_buf.astype(jnp.float32)
+        d_buf = None
+        if fold_in_kernel:
+            d_buf = (delta_buf.astype(u_buf.dtype) if delta_buf is not None
+                     else flatbuf.flatten_tree(layout, delta_tree,
+                                               batch_dims=1,
+                                               dtype=u_buf.dtype))
     return u_buf, d_buf
 
 
@@ -377,9 +380,12 @@ def _packed_vote(topo, layout, u_dev, delta_tree, rho, mask):
                               delta=delta_tree, rho=rho,
                               delta_batch_dims=1)
     # the device->edge uplink: all-gather the 1-bit payload over 'data'
-    words = topo.constrain(words, P(topo.pod_axis, topo.data_axis, None))
-    words = topo.constrain(words, P(topo.pod_axis, None, None))
-    return _popcount_vote_words(words, mask, n_dev)
+    with stages.scope("vote_exchange"):
+        words = topo.constrain(words,
+                               P(topo.pod_axis, topo.data_axis, None))
+        words = topo.constrain(words, P(topo.pod_axis, None, None))
+    with stages.scope("vote_update"):
+        return _popcount_vote_words(words, mask, n_dev)
 
 
 def _fused_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
@@ -460,21 +466,23 @@ def _fused_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
                                       delta=dt_l, rho=rho,
                                       delta_batch_dims=1)
         # the device->edge uplink: gather the 1-bit payload over 'data'
-        words = jax.lax.all_gather(words, topo.data_axis, axis=1,
-                                   tiled=True)
+        with stages.scope("vote_exchange"):
+            words = jax.lax.all_gather(words, topo.data_axis, axis=1,
+                                       tiled=True)
         if fold_mu:
             return kops.fused_vote_update_words(
                 words, v_l, m_l, float(mu_static), interpret=interpret)
-        if use_kernel:
-            vote = kops.fused_vote_update_words(
-                words, None, m_l, -1.0, interpret=interpret
-            ).astype(jnp.int8)
-        else:
-            # post-gather the voter axis holds every (virtual) client:
-            # its extent is the correct unmasked quorum size
-            vote = _popcount_vote_words(words, m_l, words.shape[1])
-        if want_update:
-            return v_l - kw["mu"] * vote.astype(v_l.dtype)
+        with stages.scope("vote_update"):
+            if use_kernel:
+                vote = kops.fused_vote_update_words(
+                    words, None, m_l, -1.0, interpret=interpret
+                ).astype(jnp.int8)
+            else:
+                # post-gather the voter axis holds every (virtual)
+                # client: its extent is the correct unmasked quorum size
+                vote = _popcount_vote_words(words, m_l, words.shape[1])
+            if want_update:
+                return v_l - kw["mu"] * vote.astype(v_l.dtype)
         return flatbuf.unflatten_tree(bucket, vote, batch_dims=1,
                                       cast=False)
 
@@ -577,13 +585,15 @@ def fused_sign_vote_update(topo: Topology, layout: flatbuf.FlatLayout,
         else:
             vote = kops.fused_sign_vote_flat(u_buf, d_buf, rho, mask,
                                              interpret=interpret)
-            new_v = v_buf - mu * vote.astype(v_buf.dtype)
+            with stages.scope("vote_update"):
+                new_v = v_buf - mu * vote.astype(v_buf.dtype)
     else:
         delta_tree = (flatbuf.unflatten_tree(layout, delta_buf,
                                              batch_dims=1, cast=False)
                       if delta_buf is not None and rho else None)
         vote = _packed_vote(topo, layout, u_dev, delta_tree, rho, mask)
-        new_v = v_buf - mu * vote.astype(v_buf.dtype)
+        with stages.scope("vote_update"):
+            new_v = v_buf - mu * vote.astype(v_buf.dtype)
     return topo.constrain(new_v, P(topo.pod_axis, None))
 
 
@@ -700,7 +710,8 @@ def fused_sign_tally_accumulate(topo: Topology, layout: flatbuf.FlatLayout,
                                             cast=False)
     words = flatbuf.pack_tree(layout, u_dev, batch_dims=2, delta=delta_tree,
                               rho=rho, delta_batch_dims=1)
-    return tally_accumulate_words(words, weights, tally)
+    with stages.scope("tally_acc"):
+        return tally_accumulate_words(words, weights, tally)
 
 
 def _tally_acc_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
@@ -747,7 +758,8 @@ def _tally_acc_shard_map(topo: Topology, layout: flatbuf.FlatLayout, u_dev,
                                           cast=False)
         words = flatbuf.pack_tree(bucket, u_l, batch_dims=2, delta=dt_l,
                                   rho=rho, delta_batch_dims=1)
-        return tally_accumulate_words(words, w_l, t_l)
+        with stages.scope("tally_acc"):
+            return tally_accumulate_words(words, w_l, t_l)
 
     fn = jax.shard_map(program, mesh=topo.mesh, in_specs=tuple(in_specs),
                        out_specs=shardflat.buf_spec(topo, layout, 2),
@@ -787,12 +799,14 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
         def program(*local):
             kw = dict(zip(names, local))
             # the ONE per-step collective of the streamed sweep
-            t = jax.lax.all_gather(kw["t"], topo.data_axis, axis=1,
-                                   tiled=True)
-            ts = jnp.sum(t.astype(jnp.int32), axis=1)
-            vote = tally_vote(ts, kw["n"]).reshape(ts.shape[0], -1)
-            if want_update:
-                return kw["v"] - kw["mu"] * vote.astype(kw["v"].dtype)
+            with stages.scope("vote_exchange"):
+                t = jax.lax.all_gather(kw["t"], topo.data_axis, axis=1,
+                                       tiled=True)
+            with stages.scope("tally_finish"):
+                ts = jnp.sum(t.astype(jnp.int32), axis=1)
+                vote = tally_vote(ts, kw["n"]).reshape(ts.shape[0], -1)
+                if want_update:
+                    return kw["v"] - kw["mu"] * vote.astype(kw["v"].dtype)
             return flatbuf.unflatten_tree(bucket, vote, batch_dims=1,
                                           cast=False)
 
@@ -806,16 +820,17 @@ def fused_tally_finish(topo: Topology, layout: flatbuf.FlatLayout,
             return topo.constrain(out, shardflat.buf_spec(topo, layout, 1))
         return flatbuf.unpad_tree(layout, out, 1)
     # the device->edge uplink: gather the int tallies over 'data'
-    t = topo.constrain(tally, P(topo.pod_axis, topo.data_axis, None))
-    t = topo.constrain(t, P(topo.pod_axis, None, None))
-    ts = jnp.sum(t.astype(jnp.int32), axis=1)
-    vote = tally_vote(ts, n_eff).reshape(ts.shape[0], -1)      # [P, n_pad]
-    vote = topo.constrain(vote, P(topo.pod_axis, None))
-    if v_buf is None:
-        return flatbuf.unflatten_tree(layout, vote, batch_dims=1,
-                                      cast=False)
-    return topo.constrain(v_buf - mu * vote.astype(v_buf.dtype),
-                          P(topo.pod_axis, None))
+    with stages.scope("vote_exchange"):
+        t = topo.constrain(tally, P(topo.pod_axis, topo.data_axis, None))
+        t = topo.constrain(t, P(topo.pod_axis, None, None))
+    with stages.scope("tally_finish"):
+        ts = jnp.sum(t.astype(jnp.int32), axis=1)
+        vote = tally_vote(ts, n_eff).reshape(ts.shape[0], -1)  # [P, n_pad]
+        vote = topo.constrain(vote, P(topo.pod_axis, None))
+        if v_buf is not None:
+            return topo.constrain(v_buf - mu * vote.astype(v_buf.dtype),
+                                  P(topo.pod_axis, None))
+    return flatbuf.unflatten_tree(layout, vote, batch_dims=1, cast=False)
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.core import stages
 from repro.kernels import ref, sign_pack as _sp, tally_acc as _ta
 from repro.kernels import ternary_quant as _tq, vote_update as _vu
 
@@ -202,6 +203,7 @@ def _slabs(u_buf: jax.Array, d_buf: jax.Array | None, rho: float):
     return g3, d3
 
 
+@stages.in_stage("sign_pack")
 def fused_pack_flat(u_buf: jax.Array, d_buf: jax.Array | None,
                     rho: float, *, interpret: bool) -> jax.Array:
     """Device-side half of the fused transport: flat floats -> packed words.
@@ -223,6 +225,7 @@ def fused_pack_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     return packed.reshape(p, d, n // PACK)
 
 
+@stages.in_stage("vote_update")
 def fused_vote_update_words(words: jax.Array, v_buf: jax.Array | None,
                             mask: jax.Array | None, mu: float, *,
                             interpret: bool) -> jax.Array:
@@ -260,9 +263,11 @@ def fused_sign_vote_flat(u_buf: jax.Array, d_buf: jax.Array | None,
     words = fused_pack_flat(u_buf, d_buf, rho, interpret=interpret)
     vote = fused_vote_update_words(words, None, mask, -1.0,
                                    interpret=interpret)
-    return vote.astype(jnp.int8)
+    with stages.scope("vote_update"):
+        return vote.astype(jnp.int8)
 
 
+@stages.in_stage("tally_acc")
 def fused_tally_acc_flat(u_buf: jax.Array, d_buf: jax.Array | None,
                          rho: float, weights: jax.Array,
                          tally: jax.Array, *, interpret: bool) -> jax.Array:

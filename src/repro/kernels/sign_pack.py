@@ -68,6 +68,10 @@ def _sign_pack_kernel(g_ref, d_ref, o_ref, bits_ref, *, rho: float):
     o_ref[...] = word
 
 
+def _sign_pack_kernel_no_delta(g_ref, o_ref, bits_ref):
+    _sign_pack_kernel(g_ref, None, o_ref, bits_ref, rho=0.0)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("rho", "block_r", "interpret"))
 def sign_pack(g: jax.Array, delta: jax.Array | None = None,
@@ -97,12 +101,11 @@ def sign_pack(g: jax.Array, delta: jax.Array | None = None,
         args.append(delta)
         kernel = functools.partial(_sign_pack_kernel, rho=rho)
     else:
-        kernel = functools.partial(
-            lambda g_ref, o_ref, bits_ref, *, rho: _sign_pack_kernel(
-                g_ref, None, o_ref, bits_ref, rho=rho), rho=rho)
+        kernel = _sign_pack_kernel_no_delta
 
     return pl.pallas_call(
         kernel,
+        name="sign_pack",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, br, LANES), lambda v, i: (v, i, 0)),

@@ -50,6 +50,10 @@ def _tally_acc_kernel(w_ref, g_ref, d_ref, t_ref, o_ref, *, rho: float):
     o_ref[...] = (t_ref[...].astype(jnp.int32) + w * s).astype(o_ref.dtype)
 
 
+def _tally_acc_kernel_no_delta(w_ref, g_ref, t_ref, o_ref):
+    _tally_acc_kernel(w_ref, g_ref, None, t_ref, o_ref, rho=0.0)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("rho", "block_r", "interpret"))
 def tally_acc(g: jax.Array, delta: jax.Array | None, w: jax.Array,
@@ -81,9 +85,7 @@ def tally_acc(g: jax.Array, delta: jax.Array | None, w: jax.Array,
         args.append(delta)
         kernel = functools.partial(_tally_acc_kernel, rho=rho)
     else:
-        kernel = functools.partial(
-            lambda w_ref, g_ref, t_ref, o_ref, *, rho: _tally_acc_kernel(
-                w_ref, g_ref, None, t_ref, o_ref, rho=rho), rho=rho)
+        kernel = _tally_acc_kernel_no_delta
     in_specs.append(pl.BlockSpec(shape, blk))
     args.append(tally)
 
@@ -94,6 +96,7 @@ def tally_acc(g: jax.Array, delta: jax.Array | None, w: jax.Array,
              else {"input_output_aliases": {len(args) - 1: 0}})
     return pl.pallas_call(
         kernel,
+        name="tally_acc",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(shape, blk),

@@ -37,6 +37,7 @@ def ternary_quant(x: jax.Array, u: jax.Array, norm: jax.Array, *,
     grid = (r // block_r, c // block_c)
     return pl.pallas_call(
         _ternary_kernel,
+        name="ternary_quant",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),

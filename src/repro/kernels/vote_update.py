@@ -61,6 +61,10 @@ def _vote_update_kernel(m_ref, p_ref, v_ref, o_ref, *, mu: float):
                            ).astype(o_ref.dtype)
 
 
+def _vote_update_kernel_unmasked(p_ref, v_ref, o_ref, *, mu: float):
+    _vote_update_kernel(None, p_ref, v_ref, o_ref, mu=mu)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("mu", "block_r", "interpret"))
 def vote_update(packed: jax.Array, v: jax.Array,
@@ -86,9 +90,7 @@ def vote_update(packed: jax.Array, v: jax.Array,
         args.insert(0, mask.astype(jnp.int32))
         kernel = functools.partial(_vote_update_kernel, mu=mu)
     else:
-        kernel = functools.partial(
-            lambda p_ref, v_ref, o_ref, *, mu: _vote_update_kernel(
-                None, p_ref, v_ref, o_ref, mu=mu), mu=mu)
+        kernel = functools.partial(_vote_update_kernel_unmasked, mu=mu)
 
     # v' aliases v: the kernel is a true read-modify-write (one HBM pass
     # over the model when the caller donates v).  Interpret mode keeps
@@ -97,6 +99,7 @@ def vote_update(packed: jax.Array, v: jax.Array,
              else {"input_output_aliases": {len(args) - 1: 0}})
     return pl.pallas_call(
         kernel,
+        name="vote_update",
         grid=grid,
         in_specs=in_specs,
         out_specs=vblk,

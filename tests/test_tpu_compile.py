@@ -14,6 +14,7 @@ the worker that runs this file loads the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +58,18 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel=None):
+    """Compile for the described chip; with ``kernel``, the Pallas call's
+    HLO instruction must carry that name (``%<kernel>.N``), which the
+    benchmark's kernel readers match in a device trace."""
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert CUSTOM_CALL in text
+    if kernel is not None:
+        calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                           + re.escape(CUSTOM_CALL), text)
+        assert calls and all(re.fullmatch(rf"{kernel}(\.\d+)?", c)
+                             for c in calls), calls
     return compiled
 
 
@@ -71,9 +80,10 @@ def test_sign_pack_compiles(one_chip, word_rows, dtype, with_delta):
     sds = jax.ShapeDtypeStruct((1, word_rows * sp.PACK, sp.LANES), dtype,
                                sharding=one_chip)
     if with_delta:
-        _compile(lambda g, d: sp.sign_pack(g, d, 0.2), sds, sds)
+        _compile(lambda g, d: sp.sign_pack(g, d, 0.2), sds, sds,
+                 kernel="sign_pack")
     else:
-        _compile(lambda g: sp.sign_pack(g), sds)
+        _compile(lambda g: sp.sign_pack(g), sds, kernel="sign_pack")
 
 
 @pytest.mark.parametrize("word_rows", ROWS)
@@ -87,9 +97,10 @@ def test_vote_update_compiles(one_chip, word_rows, masked):
     if masked:
         m = jax.ShapeDtypeStruct((1, k), jnp.int32, sharding=one_chip)
         _compile(lambda p, v, m: vu.vote_update(p, v, m, mu=1e-3),
-                 words, v, m)
+                 words, v, m, kernel="vote_update")
     else:
-        _compile(lambda p, v: vu.vote_update(p, v, mu=1e-3), words, v)
+        _compile(lambda p, v: vu.vote_update(p, v, mu=1e-3), words, v,
+                 kernel="vote_update")
 
 
 @pytest.mark.parametrize("word_rows", ROWS)
@@ -100,7 +111,9 @@ def test_tally_acc_compiles(one_chip, word_rows, tally_dtype):
     t = jax.ShapeDtypeStruct(shape, tally_dtype, sharding=one_chip)
     w = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
     _compile(lambda g, d, w, t: ta.tally_acc(g, d, w, t, rho=0.2),
-             g, g, w, t)
+             g, g, w, t, kernel="tally_acc")
+    _compile(lambda g, w, t: ta.tally_acc(g, None, w, t), g, w, t,
+             kernel="tally_acc")
 
 
 @pytest.mark.parametrize("mode", ["merged", "stream"])
@@ -128,4 +141,16 @@ def test_fused_step_compiles_with_kernels(topo, monkeypatch, mode):
     ew, dw, mask = specs.weights_abstract(tp, algo.clients)
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         state, batch, ew, dw, mask).compile()
-    assert compiled.as_text().count(CUSTOM_CALL) >= 1
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) >= 1
+    # the kernels keep their names, and every stage of the kernel
+    # route is in the op_name metadata
+    kernels = (("sign_pack", "vote_update") if mode == "merged"
+               else ("tally_acc",))
+    for name in kernels:
+        assert re.search(rf"%{name}(\.\d+)? = ", text), name
+    run = {"merged": {"sign_pack", "vote_update"},
+           "stream": {"tally_acc", "tally_finish"}}[mode]
+    got = set(re.findall(r'op_name="[^"]*?stage\.(\w+)', text))
+    assert got == {"grads", "correction", "relayout", "cloud_mean",
+                   "anchor"} | run
