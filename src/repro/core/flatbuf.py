@@ -482,6 +482,17 @@ def flatten_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
         return jnp.concatenate(
             [flatten_tree(bucket, t, batch_dims=batch_dims, dtype=dtype)
              for t in bucket_trees(layout, tree, batch_dims)], axis=-1)
+    rows = flatten_rows(layout, tree, batch_dims=batch_dims, dtype=dtype)
+    return rows.reshape(rows.shape[:-2] + (layout.n_pad,))
+
+
+@stages.in_stage("relayout")
+def flatten_rows(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
+                 dtype: Any = None) -> jax.Array:
+    """tree -> ``[*batch, n_pad/128, 128]`` lane rows of an unsharded
+    layout: :func:`flatten_tree`'s buffer before its last reshape, the
+    view the kernels read."""
+    assert layout.shards == 1, "lane rows of a sharded layout: per bucket"
     dtype = layout.dtype if dtype is None else dtype
     leaves = layout.treedef.flatten_up_to(tree)
     batch = leaves[0].shape[:batch_dims]
@@ -499,8 +510,8 @@ def flatten_tree(layout: FlatLayout, tree: PyTree, batch_dims: int = 0,
     tail = layout.n_pad // LANES - sum(p.shape[1] for p in parts)
     if tail:
         parts.append(jnp.zeros((nb, tail, LANES), dtype))
-    buf = jnp.concatenate(parts, axis=1)
-    return buf.reshape(batch + (layout.n_pad,))
+    rows = jnp.concatenate(parts, axis=1)
+    return rows.reshape(batch + rows.shape[1:])
 
 
 @stages.in_stage("relayout")

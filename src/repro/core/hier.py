@@ -979,6 +979,14 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                       if flat else delta)
                 delta_tree = _bcast_pd(topo, dt, bundle.compute_specs, None,
                                        devices=d)
+        # the fused flat route reads it as lane rows: a [P, n_pad] 16-bit
+        # buffer is not lane-row tiled, so that view is a relayout, made
+        # here once and not once per client (the body's reshape back to
+        # [P, n_pad] and the route's view cancel)
+        d_rows = None
+        if fold_dc and flat:
+            with stages.scope("relayout"):
+                d_rows = delta.buf.reshape(p, -1, flatbuf.LANES)
         # ... and so does the scaffold/mtgc edge-level term; the
         # per-client term (corr3) is sliced per client inside the loop
         ce_tree = corr3 = None
@@ -1125,7 +1133,7 @@ def make_hier_step(topo: Topology, algo: AlgoConfig, bundle: ModelBundle,
                 tally_f = votes.fused_sign_tally_accumulate(
                     topo, vlayout, u_c,
                     delta if (fold_dc and not flat) else None,
-                    delta.buf if (fold_dc and flat) else None,
+                    d_rows.reshape(p, -1) if d_rows is not None else None,
                     algo.rho if fold_dc else 0.0, w_c, tally_f)
             else:
                 s_c = jax.tree.map(signs.sgn, u_c)

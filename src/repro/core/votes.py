@@ -321,19 +321,40 @@ def tally_vote(tally: jax.Array, n_eff: jax.Array) -> jax.Array:
 
 def _fused_kernel_bufs(layout, u_dev, delta_tree, delta_buf, rho):
     """Fold rule + flat views for the Pallas route (shared by the vote-
-    only and the flat-state vote+update entry points; the correction may
-    arrive as a pytree or as a flat buffer).
+    only, the flat-state vote+update and the streamed tally entry
+    points; the correction may arrive as a pytree or as a flat buffer).
 
     The sign_pack kernel adds rho*delta in f32; folding it there is
     exact only when the reference per-leaf arithmetic is f32 too.
     Mixed/low-precision trees pre-add in each leaf's own dtype
     (identical to the tree path) to keep the transports bit-identical
-    at ULP sign boundaries.
+    at ULP sign boundaries.  A one-dtype tree with a flat correction
+    takes that add once, on the lane-row buffer its flatten builds: the
+    same ``u + rho * delta.astype(u.dtype)`` per coordinate, the
+    padding zero in both buffers, and no unflatten of the correction.
     """
     leaves = layout.treedef.flatten_up_to(u_dev)
+    # flatten in the promoted dtype over the u leaves: widening casts
+    # never move a value across zero, so the signs stay bit-identical to
+    # pack_tree's per-leaf-dtype arithmetic
+    dt = leaves[0].dtype
+    for leaf in leaves[1:]:
+        dt = jnp.promote_types(dt, leaf.dtype)
     have_delta = (delta_tree is not None or delta_buf is not None) and rho
     fold_in_kernel = (have_delta
                       and all(leaf.dtype == jnp.float32 for leaf in leaves))
+    add_on_rows = (have_delta and not fold_in_kernel and delta_tree is None
+                   and jnp.issubdtype(dt, jnp.floating)
+                   and all(leaf.dtype == dt for leaf in leaves))
+    if add_on_rows:
+        with stages.scope("relayout"):
+            rows = flatbuf.flatten_rows(layout, u_dev, batch_dims=2,
+                                        dtype=dt)
+        with stages.scope("correction"):
+            d_rows = delta_buf.reshape(delta_buf.shape[0], 1, -1,
+                                       flatbuf.LANES)
+            rows = rows + rho * d_rows.astype(dt)
+        return rows.reshape(rows.shape[:2] + (layout.n_pad,)), None
     if have_delta and not fold_in_kernel:
         if delta_tree is None:
             delta_tree = flatbuf.unflatten_tree(layout, delta_buf,
@@ -342,12 +363,6 @@ def _fused_kernel_bufs(layout, u_dev, delta_tree, delta_buf, rho):
             u_dev = jax.tree.map(
                 lambda u, dl: u + rho * dl[:, None].astype(u.dtype),
                 u_dev, delta_tree)
-    # flatten in the promoted dtype over the u leaves: widening casts
-    # never move a value across zero, so the signs stay bit-identical to
-    # pack_tree's per-leaf-dtype arithmetic
-    dt = leaves[0].dtype
-    for leaf in leaves[1:]:
-        dt = jnp.promote_types(dt, leaf.dtype)
     with stages.scope("relayout"):
         u_buf = flatbuf.flatten_tree(layout, u_dev, batch_dims=2, dtype=dt)
         if not jnp.issubdtype(u_buf.dtype, jnp.floating):

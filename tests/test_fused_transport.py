@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.core import hier, signs, votes
+from repro.core import flatbuf, hier, signs, votes
 from repro.core.topology import single_device_topology
 
 
@@ -80,6 +80,115 @@ def test_fused_pallas_interpret_route_matches_jnp(topo, monkeypatch, dtype):
     for k in tree:
         np.testing.assert_array_equal(np.asarray(v_jnp[k]),
                                       np.asarray(v_krn[k]))
+
+
+def _near_cancel(seed, rho, clients, pd=(1, 2)):
+    """bf16 directions within 0.4% of -rho * delta, a bf16 delta tree
+    and its flat buffer: the rounding of u + rho * delta decides the
+    signs."""
+    key = jax.random.PRNGKey(seed)
+    delta = {k: jax.random.normal(jax.random.fold_in(key, i),
+                                  pd[:1] + v.shape[2:], jnp.bfloat16)
+             for i, (k, v) in enumerate(_tree(pd=pd).items())}
+    us = []
+    for c in range(clients):
+        kc = jax.random.fold_in(key, 100 + c)
+        us.append({k: (-rho * d[:, None].astype(jnp.float32)
+                       * (1.0 + 0.004 * jax.random.uniform(
+                           jax.random.fold_in(kc, i), pd[1:2] + d.shape[1:],
+                           minval=-1.0, maxval=1.0))).astype(jnp.bfloat16)
+                   for i, (k, d) in enumerate(delta.items())})
+    layout = flatbuf.make_layout(us[0], batch_dims=2)
+    d_buf = flatbuf.flatten_tree(layout, delta, batch_dims=1,
+                                 dtype=jnp.bfloat16)
+    return layout, us, d_buf
+
+
+def test_fused_pallas_interpret_bf16_flat_delta_matches_jnp(topo,
+                                                            monkeypatch):
+    """bf16 directions with a bf16 flat delta: the Pallas route adds the
+    correction on the flat lane-row buffer, the jnp route per leaf; the
+    streamed tally (K = 3, one client with weight 0) and the flat
+    vote+update master must agree bitwise."""
+    rho, mu = 0.2, 1e-3
+    layout, us, d_buf = _near_cancel(seed=21, rho=rho, clients=3)
+    weights = [jnp.asarray([[1, 2]], jnp.int32),
+               jnp.asarray([[0, 0]], jnp.int32),
+               jnp.asarray([[2, 1]], jnp.int32)]
+    v_buf = jax.random.normal(jax.random.PRNGKey(5), (1, layout.n_pad))
+    mask = jnp.asarray([[True, True]])
+    rows = layout.n_pad // flatbuf.LANES
+
+    def run():
+        tally = jnp.zeros((1, 2, rows, flatbuf.LANES), jnp.int8)
+        for u, w in zip(us, weights):
+            tally = votes.fused_sign_tally_accumulate(
+                topo, layout, u, None, d_buf, rho, w, tally)
+        master = votes.fused_sign_vote_update(
+            topo, layout, us[0], d_buf, rho, mask, v_buf, mu, mu_static=mu)
+        return np.asarray(tally), np.asarray(master)
+
+    monkeypatch.delenv("REPRO_FUSED_PALLAS", raising=False)
+    t_jnp, m_jnp = run()
+    monkeypatch.setenv("REPRO_FUSED_PALLAS", "interpret")
+    t_krn, m_krn = run()
+    np.testing.assert_array_equal(t_jnp, t_krn)
+    np.testing.assert_array_equal(m_jnp, m_krn)
+    # the correction decides signs: without it the tally reads otherwise
+    t_raw = votes.fused_sign_tally_accumulate(
+        topo, layout, us[0], None, None, 0.0, weights[0],
+        jnp.zeros((1, 2, rows, flatbuf.LANES), jnp.int8))
+    t_one = votes.fused_sign_tally_accumulate(
+        topo, layout, us[0], None, d_buf, rho, weights[0],
+        jnp.zeros((1, 2, rows, flatbuf.LANES), jnp.int8))
+    flips = np.sum(np.asarray(t_raw) != np.asarray(t_one))
+    assert flips > 0.2 * 2 * layout.n           # 2 voters' real coordinates
+
+
+@pytest.mark.parametrize("case", ["bf16_flat", "f32_flat", "mixed_flat",
+                                  "bf16_tree"])
+def test_fused_kernel_bufs_route(monkeypatch, case):
+    """Which correction route ``_fused_kernel_bufs`` takes: a one-dtype
+    non-f32 tree with a flat delta adds it on the flat buffer and never
+    unflattens the delta; f32 trees hand the delta to the kernels' f32
+    fold; mixed-dtype trees and a delta given as a tree pre-add it leaf
+    by leaf."""
+    rho = 0.3
+    tree = _tree(seed=6, pd=(1, 3), dtype=jnp.bfloat16)
+    if case == "f32_flat":
+        tree = jax.tree.map(lambda x: x.astype(jnp.float32), tree)
+    if case == "mixed_flat":
+        tree["b"] = tree["b"].astype(jnp.float32)
+    delta = {k: jax.random.normal(jax.random.PRNGKey(7),
+                                  (1,) + v.shape[2:], jnp.bfloat16)
+             for k, v in tree.items()}
+    layout = flatbuf.make_layout(tree, batch_dims=2)
+    d_buf = flatbuf.flatten_tree(layout, delta, batch_dims=1,
+                                 dtype=jnp.bfloat16)
+    calls = []
+    unflatten = flatbuf.unflatten_tree
+    monkeypatch.setattr(flatbuf, "unflatten_tree",
+                        lambda *a, **k: calls.append(1) or unflatten(*a, **k))
+    flat = case != "bf16_tree"
+    u_buf, k_buf = votes._fused_kernel_bufs(
+        layout, tree, None if flat else delta, d_buf if flat else None, rho)
+
+    pre_added = jax.tree.map(
+        lambda u, dl: u + rho * dl[:, None].astype(u.dtype), tree, delta)
+    dt = jnp.bfloat16 if case.startswith("bf16") else jnp.float32
+    assert u_buf.shape == (1, 3, layout.n_pad) and u_buf.dtype == dt
+    if case == "f32_flat":
+        np.testing.assert_array_equal(
+            np.asarray(u_buf),
+            np.asarray(flatbuf.flatten_tree(layout, tree, 2, jnp.float32)))
+        np.testing.assert_array_equal(np.asarray(k_buf),
+                                      np.asarray(d_buf, np.float32))
+    else:
+        assert k_buf is None
+        want = flatbuf.flatten_tree(layout, pre_added, 2, dt)
+        np.testing.assert_array_equal(np.asarray(u_buf, np.float32),
+                                      np.asarray(want, np.float32))
+    assert bool(calls) == (case == "mixed_flat")
 
 
 def test_fused_pallas_delta_slab_mapping(topo, monkeypatch):
